@@ -18,6 +18,7 @@ from benchmarks import (ablation_int8_nu, compression_bench, engine_bench,
                         scenario_bench, server_opt, serving_bench,
                         table1_deterioration, table2_utilization,
                         table6_rounds, table_async, thm1_quadratic)
+from repro.launch.cache import setup_compile_cache
 
 MODULES = {
     "thm1": thm1_quadratic,
@@ -67,6 +68,7 @@ def main() -> None:
     ap.add_argument("--only", default=None, metavar="NAME[,NAME…]",
                     help=f"comma-separated subset of {sorted(MODULES)}")
     args = ap.parse_args()
+    setup_compile_cache()
 
     names = parse_only(args.only)
     failures = []

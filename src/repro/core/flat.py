@@ -61,6 +61,7 @@ from repro.core import compress, stages
 from repro.core import robust as robust_mod
 from repro.core.fedopt import Algorithm
 from repro.core.tree_util import tree_wsum
+from repro.kernels import backend
 from repro.kernels.calibrated_update import ref as cu_ref
 from repro.kernels.calibrated_update.kernel import (LANES,
                                                     calibrated_update_2d,
@@ -322,8 +323,7 @@ def _use_pallas_default(use_pallas: Optional[bool]) -> bool:
     buffer, bitwise-equal to the kernel (same convention as
     ``ops.calibrated_update_tree``; interpret-mode Pallas lowers to ~19
     HLO ops of grid bookkeeping, pure overhead inside a scanned round)."""
-    return jax.default_backend() == "tpu" if use_pallas is None \
-        else use_pallas
+    return backend.on_tpu() if use_pallas is None else use_pallas
 
 
 # ---------------------------------------------------------------------------
@@ -369,8 +369,7 @@ def make_flat_client_update(spec: FlatSpec,
         needs_first or (track_nu == "explicit" and uses_nu))
 
     if use_pallas:
-        interpret = (jax.default_backend() != "tpu" if interpret is None
-                     else interpret)
+        interpret = not backend.on_tpu() if interpret is None else interpret
 
         def masked_update(x, g, c, anchors, k, k_steps, lam):
             if fuse_prox:

@@ -1,9 +1,9 @@
 """Logical-axis sharding constraints (the model-side half of the mesh story).
 
 ``launch/mesh.py`` decides *which physical mesh axes* implement each logical
-axis per step kind (``mesh_rules``); this module holds that decision in
-process-global state so model code can annotate intermediates with logical
-names only:
+axis per step kind (``mesh_rules``); this module holds that decision while
+the launch layer's program is traced (``traced_under``), so model code can
+annotate intermediates with logical names only:
 
     constrain(h, "dp", None, "mp")     # (batch, seq, hidden)
 
@@ -22,45 +22,48 @@ Two deliberate behaviours (relied on by the model code):
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence
+import contextlib
+import functools
+from typing import Callable, Optional, Sequence
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-# process-global current mesh + logical→physical rules; set by the launch
-# layer (build_train_round / build_prefill / build_decode) before tracing.
+# the mesh + logical→physical rules of the program being traced; installed
+# only while the launch layer's round / serve step is traced
+# (``traced_under``), so nothing outlives the trace
 _MESH = None
 _RULES: dict[str, tuple[str, ...]] = {}
 
 
-def set_mesh_rules(mesh, rules: dict[str, Sequence[str]]) -> None:
-    """Install ``mesh`` and logical→physical ``rules`` for subsequent
-    ``constrain`` calls (idempotent; last call wins)."""
+@contextlib.contextmanager
+def mesh_rules(mesh, rules: dict[str, Sequence[str]]):
+    """Install ``mesh`` and logical→physical ``rules`` for the ``constrain``
+    calls inside the block; the previous ones come back on exit."""
     global _MESH, _RULES
-    _MESH = mesh
-    _RULES = {k: tuple(v) for k, v in rules.items()}
+    prev = _MESH, _RULES
+    _MESH, _RULES = mesh, {k: tuple(v) for k, v in rules.items()}
+    try:
+        yield
+    finally:
+        _MESH, _RULES = prev
 
 
-def unset_mesh() -> None:
-    """Clear the mesh: every later ``constrain`` is a no-op (single-device)."""
-    global _MESH, _RULES
-    _MESH = None
-    _RULES = {}
+def traced_under(mesh, rules: dict[str, Sequence[str]],
+                 fn: Callable) -> Callable:
+    """``fn`` with ``mesh_rules(mesh, rules)`` installed while it runs —
+    under ``jax.jit`` that is while it is traced, which is when the model
+    code reads them."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with mesh_rules(mesh, rules):
+            return fn(*args, **kwargs)
+    return wrapped
 
 
-def current_mesh():
-    return _MESH
-
-
-def use_mesh(mesh):
-    """Context manager making ``mesh`` the ambient jax mesh.
-
-    ``jax.set_mesh`` first shipped after the toolchain baked into this
-    container (0.4.37); there the ``Mesh`` object itself is the context
-    manager with the same scoping semantics."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+def partitioned() -> bool:
+    """Whether the program being traced spreads over a multi-device mesh."""
+    return _MESH is not None and _MESH.size > 1
 
 
 def axis_size(name: str) -> int:

@@ -391,6 +391,17 @@ class FederatedSimulation:
         self._record_dropped(hist, t0, r)
         self._record_bytes(hist, r, self.fed.n_clients)
 
+    def lower_chunk(self, r: int, t0: int = 0) -> jax.stages.Lowered:
+        """The r-round chunk ``run`` dispatches for rounds t0…t0+r-1,
+        lowered on the current state.  ``.compile()`` builds the very
+        executable the next such ``run`` reuses, so its compile time,
+        memory analysis and program text describe what runs."""
+        if self._partial:
+            raise NotImplementedError("lower_chunk covers the full-"
+                                      "participation chunk")
+        return self._chunk_fn(r).lower(self.state,
+                                       *self._chunk_inputs(t0, r))
+
     # -- partial-participation execution (fed/population.py, DESIGN.md §10) --
 
     def _run_pop_round(self, t: int, hist: History) -> None:

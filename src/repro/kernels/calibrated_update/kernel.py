@@ -7,9 +7,12 @@ parameter-sized tensor per local step.  The fused kernel streams x, g, c
 through VMEM once: 3 reads + 1 write, the bandwidth floor.
 
 TPU adaptation: the parameter pytree is flattened and lane-padded to
-(rows, 128); each grid step processes a (BLOCK_ROWS, 128) VMEM tile — the
-last-dim multiple-of-128 requirement of the VPU.  η and λ are scalar
-operands in SMEM so schedules (λ increasing over rounds) don't recompile.
+(rows, 128·k); each grid step processes one VMEM tile of at most
+BLOCK_ROWS × 128 elements (``tile_2d``) — the last-dim multiple-of-128
+requirement of the VPU.  Wide matrices tile their columns too: the flat
+round's (M, P) client rows hold P ≈ 10⁸ columns, far more than VMEM.  η and
+λ are scalar operands in SMEM so schedules (λ increasing over rounds)
+don't recompile.
 """
 from __future__ import annotations
 
@@ -22,6 +25,17 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 BLOCK_ROWS = 512            # (512, 128) fp32 tile = 256 KiB/operand in VMEM
+
+
+def tile_2d(rows: int, cols: int, block_rows: int = BLOCK_ROWS
+            ) -> tuple[int, int]:
+    """(block rows, block cols) for a (rows, cols) streaming kernel: up to
+    ``block_rows`` rows, and as many 128-lane columns as keep the tile at
+    ``block_rows × 128`` elements.  A block dim either equals the array's
+    or is a multiple of the (8, 128) tile; the grid covers ragged edges."""
+    br = min(block_rows, rows)
+    bc = min(cols, max(LANES, block_rows * LANES // br // LANES * LANES))
+    return br, bc
 
 
 def _kernel(scal_ref, x_ref, g_ref, c_ref, o_ref):
@@ -52,14 +66,15 @@ def calibrated_update_2d(x: jax.Array, g: jax.Array, c: jax.Array,
     """x, g, c: (rows, 128·k).  eta/lam: f32 scalars."""
     rows, cols = x.shape
     assert cols % LANES == 0, cols
-    br = min(block_rows, rows)
-    grid = (pl.cdiv(rows, br),)
+    br, bc = tile_2d(rows, cols, block_rows)
+    grid = (pl.cdiv(rows, br), pl.cdiv(cols, bc))
     scal = jnp.stack([jnp.asarray(eta, jnp.float32),
                       jnp.asarray(lam, jnp.float32)])
-    spec = pl.BlockSpec((br, cols), lambda i: (i, 0))
+    spec = pl.BlockSpec((br, bc), lambda i, j: (i, j))
     return pl.pallas_call(
         _kernel,
         grid=grid,
+        name="calibrated_update",
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   spec, spec, spec],
         out_specs=spec,
@@ -74,15 +89,16 @@ def calibrated_update_prox_2d(x, g, c, x0, eta, lam, mu, *,
                               interpret: bool = False) -> jax.Array:
     rows, cols = x.shape
     assert cols % LANES == 0, cols
-    br = min(block_rows, rows)
-    grid = (pl.cdiv(rows, br),)
+    br, bc = tile_2d(rows, cols, block_rows)
+    grid = (pl.cdiv(rows, br), pl.cdiv(cols, bc))
     scal = jnp.stack([jnp.asarray(eta, jnp.float32),
                       jnp.asarray(lam, jnp.float32),
                       jnp.asarray(mu, jnp.float32)])
-    spec = pl.BlockSpec((br, cols), lambda i: (i, 0))
+    spec = pl.BlockSpec((br, bc), lambda i, j: (i, j))
     return pl.pallas_call(
         _kernel_prox,
         grid=grid,
+        name="calibrated_update_prox",
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   spec, spec, spec, spec],
         out_specs=spec,

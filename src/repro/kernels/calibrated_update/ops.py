@@ -3,8 +3,8 @@
 Leaves are flattened, concatenated and lane-padded to (rows, 128) so ONE
 kernel launch covers the whole parameter vector (instead of one tiny
 launch per leaf — important for models with hundreds of small tensors).
-On non-TPU backends (this container) the kernel runs in interpret mode;
-``use_pallas=False`` falls back to the jnp oracle for A/B benchmarks.
+Off the TPU the kernel runs in interpret mode; ``use_pallas=False`` falls
+back to the jnp oracle for A/B benchmarks.
 """
 from __future__ import annotations
 
@@ -13,16 +13,13 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import backend
 from repro.kernels.calibrated_update import ref
 from repro.kernels.calibrated_update.kernel import (LANES,
                                                     calibrated_update_2d,
                                                     calibrated_update_prox_2d)
 
 PyTree = Any
-
-
-def _is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def flatten_to_2d(tree: PyTree) -> tuple[jax.Array, list, Any, int]:
@@ -59,7 +56,7 @@ def calibrated_update_tree(x: PyTree, g: PyTree, c: PyTree, eta, lam, *,
             lambda xx, gg, cc: ref.calibrated_update(xx, gg, cc, eta, lam),
             x, g, c)
     if interpret is None:
-        interpret = not _is_tpu()
+        interpret = not backend.on_tpu()
     xm, metas, treedef, n = flatten_to_2d(x)
     gm, _, _, _ = flatten_to_2d(g)
     cm, _, _, _ = flatten_to_2d(c)
@@ -77,7 +74,7 @@ def calibrated_update_prox_tree(x: PyTree, g: PyTree, c: PyTree, x0: PyTree,
             lambda xx, gg, cc, aa: ref.calibrated_update_prox(
                 xx, gg, cc, aa, eta, lam, mu), x, g, c, x0)
     if interpret is None:
-        interpret = not _is_tpu()
+        interpret = not backend.on_tpu()
     xm, metas, treedef, n = flatten_to_2d(x)
     gm, _, _, _ = flatten_to_2d(g)
     cm, _, _, _ = flatten_to_2d(c)

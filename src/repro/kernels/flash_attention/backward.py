@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -2.0 ** 30
 
@@ -151,6 +150,7 @@ def flash_attention_bwd_bhsd(q, k, v, o, lse, do, *, causal=True, window=0,
         functools.partial(_dq_kernel, scale=scale, block_q=bq, block_k=bk,
                           n_kv=n_kv, causal=causal, window=window),
         grid=(B, H, n_q, n_kv),
+        name="flash_bwd_dq",
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bk, D),
@@ -164,7 +164,7 @@ def flash_attention_bwd_bhsd(q, k, v, o, lse, do, *, causal=True, window=0,
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -179,6 +179,7 @@ def flash_attention_bwd_bhsd(q, k, v, o, lse, do, *, causal=True, window=0,
         functools.partial(_dkv_kernel, scale=scale, block_q=bq, block_k=bk,
                           n_q=n_q, n_g=g, causal=causal, window=window),
         grid=(B, Hkv, n_kv, g * n_q),
+        name="flash_bwd_dkv",
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), hq),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, j, s: (b, h, j, 0)),
@@ -195,7 +196,7 @@ def flash_attention_bwd_bhsd(q, k, v, o, lse, do, *, causal=True, window=0,
                    jax.ShapeDtypeStruct((B, Hkv, Skv, D), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -2.0 ** 30
 
@@ -113,6 +112,7 @@ def flash_attention_fwd_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return pl.pallas_call(
         kernel,
         grid=grid,
+        name="flash_fwd",
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bk, D),
@@ -131,7 +131,7 @@ def flash_attention_fwd_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),      # running denom l
             pltpu.VMEM((bq, D), jnp.float32),      # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -17,16 +17,13 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import backend
 from repro.kernels.flash_attention import ref
 from repro.kernels.flash_attention.backward import flash_attention_bwd_bhsd
 from repro.kernels.flash_attention.kernel import (flash_attention_bhsd,
                                                   flash_attention_fwd_bhsd)
 
 LANES = 128
-
-
-def _is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pad_scale(q, k, v):
@@ -53,7 +50,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if not use_pallas:
         return ref.attention(q, k, v, causal=causal, window=window)
     if interpret is None:
-        interpret = not _is_tpu()
+        interpret = not backend.on_tpu()
     dv = v.shape[-1]                 # output head dim (MLA: D_v ≠ D_qk)
     q, k, v, pad = _pad_scale(q, k, v)
     out = flash_attention_bhsd(
@@ -101,7 +98,7 @@ def flash_attention_diff(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """Differentiable flash attention.  q (B, Sq, H, D); k, v
     (B, Skv, Hkv, D) -> (B, Sq, H, D)."""
     if interpret is None:
-        interpret = not _is_tpu()
+        interpret = not backend.on_tpu()
     dv = v.shape[-1]                 # output head dim (MLA: D_v ≠ D_qk)
     q, k, v, pad = _pad_scale(q, k, v)
     out = _flash_diff_bhsd(

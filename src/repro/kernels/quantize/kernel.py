@@ -15,9 +15,9 @@ matrices with one scalar (the scale / the top-k threshold) per row:
   top-k sparsification (the k-th magnitude per row is computed outside
   the kernel — a ``lax.top_k`` reduction, not a streaming op).
 
-Same conventions as calibrated_update/kernel.py: a (BLOCK_ROWS, cols)
-VMEM tile per grid step, per-row scalars ride along as a (rows, 1) f32
-operand blocked to (BLOCK_ROWS, 1), compile-time-constant qmax in SMEM so
+Same conventions as calibrated_update/kernel.py: one ``tile_2d`` VMEM tile
+per grid step, per-row scalars ride along as a (rows, 1) f32 operand
+blocked to (block rows, 1), compile-time-constant qmax in SMEM so
 int8/int4 share one kernel.  Scale selection (padding-masked amax) is the
 caller's job: these kernels transform exactly what they are given, so the
 padding tail stays zero iff the input tail is zero — which the compressor
@@ -32,8 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-LANES = 128
-BLOCK_ROWS = 512            # (512, 128) fp32 tile = 256 KiB/operand in VMEM
+from repro.kernels.calibrated_update.kernel import (BLOCK_ROWS, LANES,
+                                                    tile_2d)
 
 
 def _quantize_kernel(scal_ref, x_ref, s_ref, o_ref):
@@ -64,14 +64,15 @@ def quantize_2d(x: jax.Array, scale: jax.Array, *, qmax: int = 127,
     [−qmax, qmax] (int4 uses qmax = 7 in the same container)."""
     rows, cols = x.shape
     assert cols % LANES == 0, cols
-    br = min(block_rows, rows)
-    grid = (pl.cdiv(rows, br),)
+    br, bc = tile_2d(rows, cols, block_rows)
+    grid = (pl.cdiv(rows, br), pl.cdiv(cols, bc))
     scal = jnp.asarray([float(qmax)], jnp.float32)
-    spec = pl.BlockSpec((br, cols), lambda i: (i, 0))
-    sspec = pl.BlockSpec((br, 1), lambda i: (i, 0))
+    spec = pl.BlockSpec((br, bc), lambda i, j: (i, j))
+    sspec = pl.BlockSpec((br, 1), lambda i, j: (i, 0))
     return pl.pallas_call(
         _quantize_kernel,
         grid=grid,
+        name="quantize",
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), spec, sspec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.int8),
@@ -87,13 +88,14 @@ def dequantize_2d(q: jax.Array, scale: jax.Array, *,
     """q: (rows, 128·k) int8 codes; scale: (rows, 1) f32.  x̂ = q·s."""
     rows, cols = q.shape
     assert cols % LANES == 0, cols
-    br = min(block_rows, rows)
-    grid = (pl.cdiv(rows, br),)
-    spec = pl.BlockSpec((br, cols), lambda i: (i, 0))
-    sspec = pl.BlockSpec((br, 1), lambda i: (i, 0))
+    br, bc = tile_2d(rows, cols, block_rows)
+    grid = (pl.cdiv(rows, br), pl.cdiv(cols, bc))
+    spec = pl.BlockSpec((br, bc), lambda i, j: (i, j))
+    sspec = pl.BlockSpec((br, 1), lambda i, j: (i, 0))
     return pl.pallas_call(
         _dequantize_kernel,
         grid=grid,
+        name="dequantize",
         in_specs=[spec, sspec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.dtype(out_dtype)),
@@ -110,13 +112,14 @@ def topk_mask_2d(x: jax.Array, thresh: jax.Array, *,
     so ≥ k elements may pass; the wire model charges exactly k)."""
     rows, cols = x.shape
     assert cols % LANES == 0, cols
-    br = min(block_rows, rows)
-    grid = (pl.cdiv(rows, br),)
-    spec = pl.BlockSpec((br, cols), lambda i: (i, 0))
-    sspec = pl.BlockSpec((br, 1), lambda i: (i, 0))
+    br, bc = tile_2d(rows, cols, block_rows)
+    grid = (pl.cdiv(rows, br), pl.cdiv(cols, bc))
+    spec = pl.BlockSpec((br, bc), lambda i, j: (i, j))
+    sspec = pl.BlockSpec((br, 1), lambda i, j: (i, 0))
     return pl.pallas_call(
         _topk_mask_kernel,
         grid=grid,
+        name="topk_mask",
         in_specs=[spec, sspec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),

@@ -17,17 +17,14 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import backend
 from repro.kernels.quantize import kernel, ref
-
-
-def _is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _resolve(use_pallas: Optional[bool],
              interpret: Optional[bool]) -> tuple[bool, bool]:
-    use_pallas = _is_tpu() if use_pallas is None else use_pallas
-    interpret = (not _is_tpu()) if interpret is None else interpret
+    use_pallas = backend.on_tpu() if use_pallas is None else use_pallas
+    interpret = (not backend.on_tpu()) if interpret is None else interpret
     return use_pallas, interpret
 
 

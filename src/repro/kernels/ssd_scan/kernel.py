@@ -11,7 +11,7 @@ dimension — states never leave VMEM.
 Grid: (B, H, n_chunks) — last axis "arbitrary"; scratch S (P, N) f32.
 Per (b, h, c) block:
 
-    cum   = cumsum(dA)                              (L,)
+    cum   = cumsum(dA)                              (L,)  [masked sums]
     y_diag = ((C·Bᵀ) ∘ exp(segsum(dA)) ∘ tril) · x  (L, P)
     y_off  = exp(cum) ∘ (C · Sᵀ)                    (L, P)
     S     ← exp(cum_L) S + xᵀ · (exp(cum_L − cum) ∘ B)
@@ -29,11 +29,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 
-def _ssd_kernel(xdt_ref, da_ref, b_ref, c_ref, y_ref, s_out_ref, s_ref, *,
-                n_chunks: int):
+def _ssd_kernel(xdt_ref, da_ref, da_row_ref, b_ref, c_ref, y_ref, s_out_ref,
+                s_ref, *, n_chunks: int):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -41,15 +40,22 @@ def _ssd_kernel(xdt_ref, da_ref, b_ref, c_ref, y_ref, s_out_ref, s_ref, *,
         s_ref[...] = jnp.zeros_like(s_ref)
 
     xdt = xdt_ref[0, 0, 0].astype(jnp.float32)       # (L, P)
-    da = da_ref[0, 0, 0, :, 0].astype(jnp.float32)   # (L,)
+    da = da_ref[0, 0, 0].astype(jnp.float32)         # (L, 1)
+    da_row = da_row_ref[0, 0, 0].astype(jnp.float32)  # (1, L)
     Bm = b_ref[0, 0, 0].astype(jnp.float32)          # (L, N)
     Cm = c_ref[0, 0, 0].astype(jnp.float32)          # (L, N)
     L = xdt.shape[0]
 
-    cum = jnp.cumsum(da)                             # (L,)
-    seg = cum[:, None] - cum[None, :]                # (L, L): cum_z − cum_s
-    tri = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1) <= \
-        jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    # prefix sums of dA as masked (L, L) reductions — Mosaic has no cumsum
+    row = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    tri = col <= row
+    cum = jnp.sum(jnp.where(tri, jnp.broadcast_to(da_row, (L, L)), 0.0),
+                  axis=1, keepdims=True)             # (L, 1): cum_z
+    cum_row = jnp.sum(jnp.where(row <= col, jnp.broadcast_to(da, (L, L)),
+                                0.0), axis=0, keepdims=True)  # (1, L): cum_s
+    total = jnp.sum(da_row, axis=1, keepdims=True)   # (1, 1): cum_L
+    seg = cum - cum_row                              # (L, L): cum_z − cum_s
     lmat = jnp.where(tri, jnp.exp(seg), 0.0)
 
     cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
@@ -58,14 +64,14 @@ def _ssd_kernel(xdt_ref, da_ref, b_ref, c_ref, y_ref, s_out_ref, s_ref, *,
                             preferred_element_type=jnp.float32)   # (L, P)
 
     s_prev = s_ref[...]                              # (P, N)
-    y += jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y += jnp.exp(cum) * jax.lax.dot_general(
         Cm, s_prev, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)          # (L,N)·(P,N)ᵀ → (L,P)
 
-    decay_end = jnp.exp(cum[-1] - cum)               # (L,)
-    s_ref[...] = (jnp.exp(cum[-1]) * s_prev
+    decay_end = jnp.exp(total - cum)                 # (L, 1)
+    s_ref[...] = (jnp.exp(total) * s_prev
                   + jax.lax.dot_general(
-                      xdt, decay_end[:, None] * Bm,
+                      xdt, decay_end * Bm,
                       (((0,), (0,)), ((), ())),
                       preferred_element_type=jnp.float32))
 
@@ -81,6 +87,7 @@ def ssd_scan_bhclp(xdt: jax.Array, da: jax.Array, b: jax.Array,
                    c: jax.Array, *, interpret: bool = False):
     """xdt (B,H,C,L,P); da (B,H,C,L,1); b, c (B,H,C,L,N).
     Returns (y (B,H,C,L,P), state (B,H,P,N) f32)."""
+    da_row = jnp.swapaxes(da, -1, -2)                # (B,H,C,1,L)
     B, H, C, L, P = xdt.shape
     N = b.shape[-1]
     grid = (B, H, C)
@@ -88,9 +95,11 @@ def ssd_scan_bhclp(xdt: jax.Array, da: jax.Array, b: jax.Array,
     return pl.pallas_call(
         kernel,
         grid=grid,
+        name="ssd_scan",
         in_specs=[
             pl.BlockSpec((1, 1, 1, L, P), lambda i, j, k: (i, j, k, 0, 0)),
             pl.BlockSpec((1, 1, 1, L, 1), lambda i, j, k: (i, j, k, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1, L), lambda i, j, k: (i, j, k, 0, 0)),
             pl.BlockSpec((1, 1, 1, L, N), lambda i, j, k: (i, j, k, 0, 0)),
             pl.BlockSpec((1, 1, 1, L, N), lambda i, j, k: (i, j, k, 0, 0)),
         ],
@@ -101,7 +110,7 @@ def ssd_scan_bhclp(xdt: jax.Array, da: jax.Array, b: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((B, H, C, L, P), xdt.dtype),
                    jax.ShapeDtypeStruct((B, H, P, N), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(xdt, da, b, c)
+    )(xdt, da, da_row, b, c)

@@ -14,13 +14,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import backend
 from repro.kernels.ssd_scan.kernel import ssd_scan_bhclp
 
 LANES = 128
-
-
-def _is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
@@ -30,7 +27,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         from repro.kernels.ssd_scan.ref import ssd_chunked
         return ssd_chunked(x, dt, A, B, C, chunk)
     if interpret is None:
-        interpret = not _is_tpu()
+        interpret = not backend.on_tpu()
     b, l, h, p = x.shape
     g, n = B.shape[2], B.shape[3]
     L = min(chunk, l)

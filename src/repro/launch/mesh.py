@@ -8,6 +8,16 @@ this module never touches jax device state — callers (dryrun.py) set
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with ``Auto`` axis types: the model code's
+    ``with_sharding_constraint`` calls (dist.constrain) are layout hints
+    the partitioner propagates from, not the type-level assertions
+    ``Explicit`` axes (jax.make_mesh's default) would make them."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, variant: str = "tp16"):
@@ -29,7 +39,7 @@ def make_production_mesh(*, multi_pod: bool = False, variant: str = "tp16"):
                 else ("data", "batch", "model"))
     else:
         raise ValueError(variant)
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def recommended_variant(cfg) -> str:
@@ -41,7 +51,7 @@ def recommended_variant(cfg) -> str:
 
 def make_local_mesh(data: int = 2, model: int = 2):
     """Small mesh over host devices for tests (set device_count first)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple[str, ...]:

@@ -9,14 +9,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ShapeConfig
-from repro.dist import set_mesh_rules, use_mesh
+from repro.dist import traced_under
 from repro.launch import specs as specs_lib
 from repro.launch.mesh import mesh_rules
 from repro.models.model import serve_decode, serve_prefill
 
 
 def build_prefill(cfg: ModelConfig, shape: ShapeConfig, mesh):
-    set_mesh_rules(mesh, mesh_rules(mesh, kind="prefill"))
     bundle = specs_lib.serve_specs(cfg, shape, mesh, kind="prefill")
     sh = lambda t: specs_lib.to_shardings(t, mesh)
 
@@ -24,7 +23,7 @@ def build_prefill(cfg: ModelConfig, shape: ShapeConfig, mesh):
         return serve_prefill(params, batch, cfg, caches=caches)
 
     jitted = jax.jit(
-        step,
+        traced_under(mesh, mesh_rules(mesh, kind="prefill"), step),
         in_shardings=(sh(bundle["param_ps"]), sh(bundle["batch_ps"]),
                       sh(bundle["cache_ps"])),
         out_shardings=(None, sh(bundle["cache_ps"])),
@@ -35,7 +34,6 @@ def build_prefill(cfg: ModelConfig, shape: ShapeConfig, mesh):
 def build_decode(cfg: ModelConfig, shape: ShapeConfig, mesh, *,
                  kind: str = "decode"):
     """kind "decode" (batch over data) or "long" (cache seq over data)."""
-    set_mesh_rules(mesh, mesh_rules(mesh, kind=kind))
     bundle = specs_lib.serve_specs(cfg, shape, mesh, kind=kind)
     sh = lambda t: specs_lib.to_shardings(t, mesh)
     seq_shard = kind == "long"
@@ -45,7 +43,7 @@ def build_decode(cfg: ModelConfig, shape: ShapeConfig, mesh, *,
                             seq_shard=seq_shard)
 
     jitted = jax.jit(
-        step,
+        traced_under(mesh, mesh_rules(mesh, kind=kind), step),
         in_shardings=(sh(bundle["param_ps"]), sh(bundle["batch_ps"]),
                       sh(bundle["cache_ps"]), None),
         out_shardings=(None, sh(bundle["cache_ps"])),
@@ -54,7 +52,7 @@ def build_decode(cfg: ModelConfig, shape: ShapeConfig, mesh, *,
 
 
 def lower_serve(cfg: ModelConfig, shape: ShapeConfig, mesh, *, kind: str):
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if kind == "prefill":
             jitted, bundle = build_prefill(cfg, shape, mesh)
             lowered = jitted.lower(bundle["params"], bundle["batch"],
@@ -77,7 +75,6 @@ def build_personalized_decode(cfg: ModelConfig, shape: ShapeConfig, mesh,
     decode — one program serves every client's personalized view."""
     from repro.serving.personalized import personalized_decode
 
-    set_mesh_rules(mesh, mesh_rules(mesh, kind="decode"))
     bundle = specs_lib.serve_specs(cfg, shape, mesh, kind="decode")
     sh = lambda t: specs_lib.to_shardings(t, mesh)
     b = shape.global_batch
@@ -93,7 +90,7 @@ def build_personalized_decode(cfg: ModelConfig, shape: ShapeConfig, mesh,
                                    caches, pos_offset)
 
     jitted = jax.jit(
-        step,
+        traced_under(mesh, mesh_rules(mesh, kind="decode"), step),
         in_shardings=(sh(bundle["base_ps"]), sh(bundle["delta_ps"]),
                       sh(bundle["batch_ps"]), sh(bundle["cache_ps"]), None),
         out_shardings=(None, sh(bundle["cache_ps"])),
@@ -103,7 +100,7 @@ def build_personalized_decode(cfg: ModelConfig, shape: ShapeConfig, mesh,
 
 def lower_personalized_serve(cfg: ModelConfig, shape: ShapeConfig, mesh,
                              spec):
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted, bundle = build_personalized_decode(cfg, shape, mesh, spec)
         pos = jax.ShapeDtypeStruct((shape.global_batch,), jnp.int32)
         lowered = jitted.lower(bundle["base"], bundle["deltas"],

@@ -21,7 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import FedConfig, ModelConfig, ShapeConfig
 from repro.core import engine, rounds, stages
 from repro.core.fedopt import get_algorithm
-from repro.dist import set_mesh_rules, use_mesh
+from repro.dist import traced_under
 from repro.launch import specs as specs_lib
 from repro.launch.mesh import data_axes, mesh_rules, model_axes
 from repro.models.model import lm_loss
@@ -78,8 +78,6 @@ def build_train_round(cfg: ModelConfig, shape: ShapeConfig, mesh,
     (DESIGN.md §13), and ``fed.master_dtype`` keeps an f32 master over
     bf16 compute."""
     algo = get_algorithm(fed.algorithm, fed)
-    set_mesh_rules(mesh, mesh_rules(mesh, kind="train"))
-
     loss_fn = functools.partial(lm_loss, cfg=cfg)
     if fed.param_layout == "flat":
         from repro.core import flat as flat_lib
@@ -90,6 +88,9 @@ def build_train_round(cfg: ModelConfig, shape: ShapeConfig, mesh,
         round_fn = flat_lib.make_flat_round(
             fspec, lambda p, b: loss_fn(p, b), algo, lr=fed.lr,
             k_max=k_max,
+            # a Mosaic call cannot be partitioned: over several devices
+            # the local step takes its XLA path
+            use_pallas=False if mesh.size > 1 else None,
             param_constraint=make_flat_param_constraint(mesh, fspec.p))
     else:
         round_fn = rounds.make_round(
@@ -97,6 +98,7 @@ def build_train_round(cfg: ModelConfig, shape: ShapeConfig, mesh,
             spmd_axis_name=data_axes(mesh) or None,
             param_constraint=make_param_constraint(mesh))
         bundle = specs_lib.train_specs(cfg, shape, mesh, algo, k_max=k_max)
+    round_fn = traced_under(mesh, mesh_rules(mesh, kind="train"), round_fn)
     if chunk_rounds > 1:
         # sharding layouts are pinned by the in-scan param_constraint;
         # stacked inputs keep their per-round specs on the trailing axes.
@@ -116,7 +118,7 @@ def build_train_round(cfg: ModelConfig, shape: ShapeConfig, mesh,
 def lower_train(cfg: ModelConfig, shape: ShapeConfig, mesh, fed: FedConfig,
                 *, k_max: int = 4):
     """.lower() the round on ShapeDtypeStructs (no allocation)."""
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted, bundle = build_train_round(cfg, shape, mesh, fed, k_max=k_max)
         s = bundle["specs"]
         lowered = jitted.lower(s["state"], s["batches"], s["k_steps"],
@@ -139,13 +141,13 @@ def build_population_round(cfg: ModelConfig, shape: ShapeConfig, mesh,
     arguments).  Call under ``with mesh:``.
     """
     algo = get_algorithm(fed.algorithm, fed)
-    set_mesh_rules(mesh, mesh_rules(mesh, kind="train"))
     loss_fn = functools.partial(lm_loss, cfg=cfg)
     round_fn = stages.make_cohort_round(
         lambda p, b: loss_fn(p, b), algo, lr=fed.lr, k_max=k_max,
         nu_decay=fed.cohort_nu_decay,
         spmd_axis_name=data_axes(mesh) or None,
         param_constraint=make_param_constraint(mesh))
+    round_fn = traced_under(mesh, mesh_rules(mesh, kind="train"), round_fn)
     bundle = specs_lib.population_train_specs(cfg, shape, mesh, algo,
                                               m_population, k_max=k_max)
     sh = lambda tree: specs_lib.to_shardings(tree, mesh)
@@ -162,7 +164,7 @@ def build_population_round(cfg: ModelConfig, shape: ShapeConfig, mesh,
 def lower_population(cfg: ModelConfig, shape: ShapeConfig, mesh,
                      fed: FedConfig, *, m_population: int, k_max: int = 4):
     """.lower() the population cohort round on ShapeDtypeStructs."""
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted, bundle = build_population_round(
             cfg, shape, mesh, fed, m_population=m_population, k_max=k_max)
         s = bundle["specs"]
@@ -179,7 +181,7 @@ def _fit_mesh():
     """Production mesh when 256/512 devices exist; else the largest
     (data, model) grid over whatever this run has (CPU dev: 1×1)."""
     import numpy as np
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.mesh import make_mesh, make_production_mesh
     n = len(jax.devices())
     if n >= 512:
         return make_production_mesh(multi_pod=True)
@@ -189,7 +191,7 @@ def _fit_mesh():
     while data * 2 <= n and data < 16:
         data *= 2
     model = max(n // data, 1)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def main() -> None:
@@ -201,6 +203,7 @@ def main() -> None:
     from repro.configs.shapes import SHAPES
     from repro.data.synthetic import lm_sequences
     from repro.launch import specs as specs_lib
+    from repro.launch.cache import setup_compile_cache
     from repro.launch.distributed import bootstrap, is_coordinator
     from repro.launch.mesh import n_clients
 
@@ -223,6 +226,7 @@ def main() -> None:
     ap.add_argument("--reduced", action="store_true",
                     help="reduced model + tiny shape (CPU/dev runs)")
     args = ap.parse_args()
+    setup_compile_cache()
 
     bootstrap()
     mesh = _fit_mesh()
@@ -237,7 +241,7 @@ def main() -> None:
                     param_layout=args.param_layout,
                     master_dtype=args.master_dtype)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         chunk = max(args.chunk_rounds, 1)
         jitted, bundle = build_train_round(cfg, shape, mesh, fed,
                                            k_max=args.k_max,

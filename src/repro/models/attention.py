@@ -5,8 +5,10 @@ prefill runs the Pallas flash kernel on TPU (scores stay in VMEM — the
 jnp fallback elsewhere; decode attends a positional KV cache (optionally
 sequence-sharded for long contexts).
 
-``REPRO_FLASH_ATTENTION``: ``auto`` (default — kernel on TPU only),
-``interpret`` (force the kernel in interpret mode; tests), ``off``.
+``REPRO_FLASH_ATTENTION``: ``auto`` (default — kernel on TPU, except in a
+program traced under a multi-device mesh, ``dist.partitioned``, since XLA
+cannot partition a Mosaic call), ``interpret`` (force the kernel in
+interpret mode; tests), ``off``.
 """
 from __future__ import annotations
 
@@ -17,7 +19,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.dist import axis_size, constrain
+from repro.dist import axis_size, constrain, partitioned
+from repro.kernels import backend
 from repro.models.layers import apply_rope, dense_init, rms_norm, softcap
 
 
@@ -31,7 +34,7 @@ def _flash_ok(S: int, logit_cap: float, q_pos) -> bool:
     mode = _flash_mode()
     if mode == "off":
         return False
-    if mode == "auto" and jax.default_backend() != "tpu":
+    if mode == "auto" and (not backend.on_tpu() or partitioned()):
         return False
     return logit_cap == 0.0 and S % 128 == 0
 

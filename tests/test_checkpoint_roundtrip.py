@@ -16,6 +16,7 @@ from repro.configs.registry import get_arch
 from repro.core import flat
 from repro.data import DeviceBatcher, fedprox_synthetic
 from repro.fed import FederatedSimulation
+from repro.fed.scenarios import _corrupt_set
 from repro.models import model as M_model
 from repro.models.simple import lr_loss
 from repro.serving import (PersonalizedServeEngine, Request, load_snapshot,
@@ -37,6 +38,20 @@ def _fed(**kw):
     kw.setdefault("k_var", 2.0)
     kw.setdefault("k_mode", "random")
     return FedConfig(n_clients=M, lr=0.05, calibration_rate=0.5, **kw)
+
+
+# with jax_threefry_partitionable (jax's default since 0.5) the seed-0
+# corrupt set at rate 0.25 is empty for M = 8; seed 2 corrupts two
+# clients, so the nan_inject tests exercise a real attack
+NAN_SEED = 2
+
+
+def _nan_fed(**kw):
+    """A nan_inject config whose persistent corrupt set is non-empty."""
+    fed = _fed(scenario="nan_inject", scenario_rate=0.25, seed=NAN_SEED,
+               **kw)
+    assert np.asarray(_corrupt_set(M, fed.seed, fed.scenario_rate)).any()
+    return fed
 
 
 def _params():
@@ -139,9 +154,8 @@ _HEALTH_KEYS = ("hz_nonfinite", "hz_mean", "hz_var", "hz_count", "hz_until")
 def test_health_state_roundtrips_bit_exact(task, tmp_path, layout):
     """The per-client health vectors ride the same checkpoint as the
     model/ν/EF state, bit-for-bit, on both layouts."""
-    fed = _fed(param_layout=layout, scenario="nan_inject",
-               scenario_rate=0.25, defense="trimmed_mean",
-               quarantine_window=3)
+    fed = _nan_fed(param_layout=layout, defense="trimmed_mean",
+                   quarantine_window=3)
     sim = FederatedSimulation(lr_loss, _params(), fed, task)
     sim.run(3, eval_every=3)
     assert np.asarray(sim.state["hz_nonfinite"]).sum() > 0
@@ -157,8 +171,7 @@ def test_health_state_roundtrips_bit_exact(task, tmp_path, layout):
 def test_cohort_absentee_health_rows_untouched(task):
     """A client outside the sampled cohort reports nothing: its health
     rows must stay bit-identical (no decay, no accidental scatter)."""
-    fed = _fed(cohort_size=3, scenario="nan_inject", scenario_rate=0.25,
-               defense="median", quarantine_window=4)
+    fed = _nan_fed(cohort_size=3, defense="median", quarantine_window=4)
     sim = FederatedSimulation(lr_loss, _params(), fed, task)
     before = {k: np.asarray(sim.state[k]).copy() for k in _HEALTH_KEYS}
     sim.run(1)
@@ -173,8 +186,7 @@ def test_cohort_absentee_health_rows_untouched(task):
 def test_quarantine_survives_resume(task, tmp_path):
     """A quarantine window in force at save time is still in force after
     load: the restored engine keeps excluding the flagged clients."""
-    fed = _fed(scenario="nan_inject", scenario_rate=0.25,
-               defense="trimmed_mean", quarantine_window=8)
+    fed = _nan_fed(defense="trimmed_mean", quarantine_window=8)
     sim = FederatedSimulation(lr_loss, _params(), fed, task)
     sim.run(2, eval_every=2)
     assert np.asarray(sim.state["hz_until"]).max() > 0

@@ -30,7 +30,7 @@ from repro.configs.registry import get_arch
 from repro.configs.base import ShapeConfig
 from repro.core import rounds
 from repro.core.fedopt import get_algorithm
-from repro.dist import set_mesh_rules, unset_mesh, use_mesh
+from repro import dist
 from repro.launch.mesh import make_local_mesh
 from repro.launch import train as train_lib, specs as specs_lib
 from repro.models import model as M
@@ -49,7 +49,6 @@ w = jnp.full((m,), 0.25, jnp.float32)
 loss = lambda p, bt: M.lm_loss(p, bt, cfg)
 
 # --- single device ---------------------------------------------------------
-unset_mesh()
 state0 = rounds.init_state(params, m, algo)
 fn = jax.jit(rounds.make_round(loss, algo, lr=fed.lr, k_max=k_max))
 ref_state, ref_metrics = fn(state0, batches, ks, w)
@@ -57,7 +56,7 @@ ref_state, ref_metrics = fn(state0, batches, ks, w)
 # --- (data=4, model=2) mesh --------------------------------------------------
 mesh = make_local_mesh(4, 2)
 shape = ShapeConfig("t", seq_len=s, global_batch=m * b, kind="train")
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     jitted, bundle = train_lib.build_train_round(cfg, shape, mesh, fed,
                                                  k_max=k_max)
     state0b = rounds.init_state(params, m, algo)
@@ -68,6 +67,8 @@ with use_mesh(mesh):
     spmd_state, spmd_metrics = jitted(state0b, batches_s,
                                       jax.device_put(ks, sh(ps["k_steps"])),
                                       jax.device_put(w, sh(ps["weights"])))
+# the mesh rules lived only while the round was traced
+assert not dist.partitioned() and dist.axis_size("mp") == 1
 
 for pref, pspmd in zip(jax.tree.leaves(ref_state["params"]),
                        jax.tree.leaves(spmd_state["params"])):
@@ -89,7 +90,6 @@ def test_sharded_decode_matches_single_device():
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs.base import ShapeConfig, reduced
 from repro.configs.registry import get_arch
-from repro.dist import unset_mesh, use_mesh
 from repro.launch.mesh import make_local_mesh
 from repro.launch import serve as serve_lib
 from repro.models import model as M
@@ -101,12 +101,11 @@ params = M.init_params(key, cfg)
 toks = jax.random.randint(key, (B, 1), 0, cfg.vocab)
 caches = M.init_caches(cfg, B, max_len=S, dtype=jnp.float32)
 
-unset_mesh()
 ref_logits, _ = M.serve_decode(params, {"tokens": toks}, caches, 0, cfg)
 
 mesh = make_local_mesh(4, 2)
 shape = ShapeConfig("d", seq_len=S, global_batch=B, kind="decode")
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     jitted, bundle = serve_lib.build_decode(cfg, shape, mesh, kind="decode")
     spmd_logits, _ = jitted(params, {"tokens": toks}, caches,
                             jnp.zeros((), jnp.int32))

@@ -296,12 +296,16 @@ def test_replay_swaps_mid_stream(setup):
 def test_personalized_lowering_single_device(setup):
     """The sharded decode path lowers on a 1×1 local mesh and its bundle
     carries the flat base/delta shapes."""
+    from repro import dist
     from repro.launch.mesh import make_local_mesh
     from repro.launch.serve import lower_personalized_serve
     cfg, params, spec, base = setup
     mesh = make_local_mesh(1, 1)
     shape = ShapeConfig("t", seq_len=32, global_batch=4, kind="decode")
     lowered, bundle = lower_personalized_serve(cfg, shape, mesh, spec)
+    # the mesh rules lived only while the step was traced: later programs
+    # in this process run unsharded
+    assert dist.axis_size("mp") == 1 and not dist.partitioned()
     assert bundle["base"].shape == (spec.p,)
     assert bundle["deltas"].shape == (4, spec.p)
     assert lowered.compile() is not None

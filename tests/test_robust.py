@@ -19,6 +19,7 @@ from repro.fed import (BufferedAsyncSimulation, FederatedSimulation,
                        SCENARIOS, garbage_scenario, make_scenario,
                        nan_inject_scenario, scale_attack_scenario,
                        sign_flip_scenario)
+from repro.fed.scenarios import _corrupt_set
 from repro.models.simple import lr_loss
 
 M = 8
@@ -39,6 +40,20 @@ def _fed(**kw):
     kw.setdefault("k_var", 2.0)
     kw.setdefault("k_mode", "random")
     return FedConfig(n_clients=M, lr=0.05, calibration_rate=0.5, **kw)
+
+
+# with jax_threefry_partitionable (jax's default since 0.5) the seed-0
+# corrupt set at rate 0.25 is empty for M = 8; seed 2 corrupts two
+# clients, so the nan_inject tests exercise a real attack
+NAN_SEED = 2
+
+
+def _nan_fed(**kw):
+    """A nan_inject config whose persistent corrupt set is non-empty."""
+    fed = _fed(scenario="nan_inject", scenario_rate=0.25, seed=NAN_SEED,
+               **kw)
+    assert np.asarray(_corrupt_set(M, fed.seed, fed.scenario_rate)).any()
+    return fed
 
 
 def _params():
@@ -328,8 +343,7 @@ def test_tree_and_flat_agree_under_attack(task, defense):
 
 def test_undefended_nan_inject_raises_at_eval(task):
     sim = FederatedSimulation(lr_loss, _params(),
-                              _fed(scenario="nan_inject",
-                                   scenario_rate=0.25), task,
+                              _nan_fed(), task,
                               eval_fn=_eval)
     with pytest.raises(FloatingPointError, match="non-finite"):
         sim.run(4, eval_every=1)
@@ -338,9 +352,8 @@ def test_undefended_nan_inject_raises_at_eval(task):
 @pytest.mark.parametrize("defense", ["median", "trimmed_mean", "krum"])
 def test_defended_nan_inject_stays_finite(task, defense):
     sim = FederatedSimulation(lr_loss, _params(),
-                              _fed(scenario="nan_inject",
-                                   scenario_rate=0.25, defense=defense,
-                                   quarantine_window=3), task,
+                              _nan_fed(defense=defense,
+                                       quarantine_window=3), task,
                               eval_fn=_eval)
     hist = sim.run(4, eval_every=1)
     assert all(np.isfinite(hist.metric))
@@ -351,9 +364,8 @@ def test_defended_nan_inject_stays_finite(task, defense):
 def test_defended_async_nan_inject_stays_finite(task):
     sim = BufferedAsyncSimulation(
         lr_loss, _params(),
-        _fed(scenario="nan_inject", scenario_rate=0.25,
-             defense="trimmed_mean", quarantine_window=3,
-             buffer_size=4), task, eval_fn=_eval)
+        _nan_fed(defense="trimmed_mean", quarantine_window=3,
+                 buffer_size=4), task, eval_fn=_eval)
     hist = sim.run(6, eval_every=1)
     assert all(np.isfinite(hist.metric))
     for leaf in jax.tree.leaves(sim.params):
@@ -364,9 +376,7 @@ def test_guard_without_quarantine_keeps_nu_finite(task):
     """defense alone (no quarantine) must still never write NaN into the
     master or ν — the final guard, not the health layer, provides this."""
     sim = FederatedSimulation(lr_loss, _params(),
-                              _fed(scenario="nan_inject",
-                                   scenario_rate=0.25,
-                                   defense="median"), task)
+                              _nan_fed(defense="median"), task)
     sim.run(3, eval_every=3)
     for key in ("params", "nu", "nu_i"):
         for leaf in jax.tree.leaves(sim.state[key]):
@@ -378,8 +388,7 @@ def test_guard_without_quarantine_keeps_nu_finite(task):
 # ---------------------------------------------------------------------------
 
 def test_nonfinite_reporters_get_quarantined(task):
-    fed = _fed(scenario="nan_inject", scenario_rate=0.25,
-               defense="trimmed_mean", quarantine_window=4)
+    fed = _nan_fed(defense="trimmed_mean", quarantine_window=4)
     sim = FederatedSimulation(lr_loss, _params(), fed, task)
     hist = sim.run(4, eval_every=1)
     hit = np.asarray(sim.state["hz_nonfinite"]) > 0
@@ -405,10 +414,8 @@ def test_quarantine_state_keys_allocated_only_when_active(task):
 
 def test_flatten_state_passes_health_keys_through(task):
     sim = FederatedSimulation(lr_loss, _params(),
-                              _fed(scenario="nan_inject",
-                                   scenario_rate=0.25,
-                                   defense="median",
-                                   quarantine_window=2), task)
+                              _nan_fed(defense="median",
+                                       quarantine_window=2), task)
     sim.run(1)
     spec = sim._spec
     flat_state = flat_mod.flatten_state(spec, sim.state)
