@@ -60,7 +60,6 @@ import numpy as np
 from repro.core import compress, stages
 from repro.core import robust as robust_mod
 from repro.core.fedopt import Algorithm
-from repro.core.tree_util import tree_wsum
 from repro.kernels import backend
 from repro.kernels.calibrated_update import ref as cu_ref
 from repro.kernels.calibrated_update.kernel import (LANES,
@@ -255,8 +254,11 @@ def flat_value_and_grad(spec: FlatSpec,
     vag = jax.value_and_grad(loss_fn)
 
     def run(flat_row: jax.Array, batch: PyTree):
-        loss, g = vag(view_tree(spec, flat_row), batch)
-        return loss, flat_cotangent(spec, g)
+        with jax.named_scope("fed.flat_boundary"):
+            views = view_tree(spec, flat_row)
+        loss, g = vag(views, batch)
+        with jax.named_scope("fed.flat_boundary"):
+            return loss, flat_cotangent(spec, g)
 
     return run
 
@@ -371,6 +373,7 @@ def make_flat_client_update(spec: FlatSpec,
     if use_pallas:
         interpret = not backend.on_tpu() if interpret is None else interpret
 
+        @jax.named_scope("fed.local_step")
         def masked_update(x, g, c, anchors, k, k_steps, lam):
             if fuse_prox:
                 upd = calibrated_update_prox_2d(x, g, c, anchors, lr, lam,
@@ -381,6 +384,7 @@ def make_flat_client_update(spec: FlatSpec,
                                            interpret=interpret)
             return jnp.where((k < k_steps)[:, None], upd, x)
     else:
+        @jax.named_scope("fed.local_step")
         def masked_update(x, g, c, anchors, k, k_steps, lam):
             """Oracle with the K_i mask FOLDED into the update as a
             per-row step size η_i ∈ {η, 0}: an inactive row computes
@@ -402,6 +406,7 @@ def make_flat_client_update(spec: FlatSpec,
     # view cast is the only master→compute crossing
     grad_fn = jax.vmap(flat_value_and_grad(spec, loss_fn))
 
+    @jax.named_scope("fed.client_update")
     def run(anchor, c_all, batches, k_steps, lam):
         m = k_steps.shape[0]
         anchors = (anchor if per_client_anchor
@@ -459,6 +464,7 @@ def make_flat_client_update(spec: FlatSpec,
     return run
 
 
+@jax.named_scope("fed.orientation")
 def _flat_transmit(spec: FlatSpec, algo: Algorithm, params0, x_i, g0_i,
                    acc_i, c_all, kf, kbar, lr, lam, *,
                    track_nu: str = "delta", quantize_transmit: bool = False,
@@ -538,7 +544,7 @@ def make_flat_round(spec: FlatSpec,
             anchor = params0
             nu_bc = state["nu"] if algo.uses_nu else None
 
-        c_all = (nu_bc[None] - state["nu_i"]
+        c_all = (stages.corrections(nu_bc, state["nu_i"])
                  if algo.uses_nu else None)                # (M, P)
 
         x_i, g0_i, acc_i, loss0 = client_update(anchor, c_all, batches,
@@ -592,7 +598,8 @@ def make_flat_round(spec: FlatSpec,
                 transmit, w_nu = rb.nu(
                     transmit, weights, state, state["round"],
                     jnp.arange(x_i.shape[0], dtype=jnp.int32))
-            new_state["nu"] = constrain(tree_wsum(w_nu, transmit), 0)
+            new_state["nu"] = constrain(stages.transmit_mix(w_nu, transmit),
+                                        0)
             new_state["nu_i"] = constrain(avg_g, 1)
 
         if rb is not None:
@@ -664,7 +671,7 @@ def make_flat_cohort_round(spec: FlatSpec,
             anchor = params0
             nu_bc = state["nu"] if algo.uses_nu else None
 
-        c_all = (nu_bc[None] - state["nu_i"][cohort]
+        c_all = (stages.corrections(nu_bc, state["nu_i"], cohort)
                  if algo.uses_nu else None)                # (C, P) rows
 
         x_i, g0_i, acc_i, loss0 = client_update(anchor, c_all, batches,
@@ -708,7 +715,7 @@ def make_flat_cohort_round(spec: FlatSpec,
             if rb is not None:
                 transmit, w_nu = rb.nu(transmit, cweights, state,
                                        state["round"], cohort)
-            contrib = tree_wsum(w_nu, transmit)
+            contrib = stages.transmit_mix(w_nu, transmit)
             new_nu = stages.nu_mass_mix(state["nu"], contrib, mass)
             new_state["nu"] = constrain(new_nu, 0)
             new_state["nu_i"] = constrain(

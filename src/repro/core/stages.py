@@ -92,6 +92,7 @@ def make_client_update(loss_fn: Callable[[PyTree, PyTree], jax.Array],
     needs_first = algo.selector in ("fedagrac", "first", "reverse")
     grad_fn = jax.value_and_grad(loss_fn)
 
+    @jax.named_scope("fed.client_update")
     def client_run(anchor, c_i, batch_i, K_i, lam):
         lam_c = (jax.tree.map(lambda c: _typed_scale(lam, c), c_i)
                  if algo.uses_nu else None)
@@ -104,13 +105,14 @@ def make_client_update(loss_fn: Callable[[PyTree, PyTree], jax.Array],
                 g = jax.tree.map(lambda gg, xx, x0: gg + algo.prox_mu * (xx - x0),
                                  g, x, anchor)
             active = k < K_i
-            if algo.uses_nu:
-                upd = jax.tree.map(lambda xx, gg, cc: xx - lr * (gg + cc),
-                                   x, g, lam_c)
-            else:
-                upd = jax.tree.map(lambda xx, gg: xx - lr * gg, x, g)
-            x = jax.tree.map(lambda old, new: jnp.where(active, new, old),
-                             x, upd)
+            with jax.named_scope("fed.local_step"):
+                if algo.uses_nu:
+                    upd = jax.tree.map(
+                        lambda xx, gg, cc: xx - lr * (gg + cc), x, g, lam_c)
+                else:
+                    upd = jax.tree.map(lambda xx, gg: xx - lr * gg, x, g)
+                x = jax.tree.map(
+                    lambda old, new: jnp.where(active, new, old), x, upd)
             if needs_first:
                 g0 = jax.tree.map(lambda a, gg: jnp.where(k == 0, gg, a),
                                   g0, g)
@@ -144,12 +146,14 @@ def zero_corrections(params: PyTree, m: int) -> PyTree:
 # stage 2: aggregation
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("fed.aggregate")
 def aggregate_mean(params0: PyTree, x_i: PyTree, kf: jax.Array,
                    weights: jax.Array, kbar: jax.Array) -> PyTree:
     """Plain weighted average  Σ ω_i x⁽ⁱ⁾."""
     return tree_wsum(weights, x_i)
 
 
+@jax.named_scope("fed.aggregate")
 def aggregate_fednova(params0: PyTree, x_i: PyTree, kf: jax.Array,
                       weights: jax.Array, kbar: jax.Array) -> PyTree:
     """FedNova:  x̃ + K̄ Σ ω_i (x⁽ⁱ⁾ − x̃)/K_i  (Wang et al. 2020)."""
@@ -168,6 +172,7 @@ AGGREGATORS: dict[str, Callable] = {
 }
 
 
+@jax.named_scope("fed.aggregate")
 def buffered_mean(params: PyTree, anchor_i: PyTree, x_i: PyTree,
                   kf: jax.Array, sweights: jax.Array,
                   kbar: jax.Array) -> PyTree:
@@ -185,6 +190,7 @@ def buffered_mean(params: PyTree, anchor_i: PyTree, x_i: PyTree,
                       ).astype(p.dtype), params, deltas)
 
 
+@jax.named_scope("fed.aggregate")
 def buffered_fednova(params: PyTree, anchor_i: PyTree, x_i: PyTree,
                      kf: jax.Array, sweights: jax.Array,
                      kbar: jax.Array) -> PyTree:
@@ -220,6 +226,7 @@ def delivered_weights(weights: jax.Array, k_eff: jax.Array,
     return weights * frac
 
 
+@jax.named_scope("fed.orientation")
 def nu_mass_mix(nu: PyTree, contrib: PyTree, mass: jax.Array) -> PyTree:
     """ν ← (1 − ρ) ν + (ρ/Σw̃)·Σ w̃ transmitᵢ with ρ = min(Σw̃, 1): keep ρ
     of the new signal, renormalized — convex even when duplicate reporters
@@ -234,6 +241,7 @@ def nu_mass_mix(nu: PyTree, contrib: PyTree, mass: jax.Array) -> PyTree:
                       ).astype(n.dtype), nu, contrib)
 
 
+@jax.named_scope("fed.orientation")
 def scatter_nu_rows(nu_i: PyTree, new_nu: PyTree, avg_g: PyTree,
                     ids: jax.Array, nu_decay: float = 0.0) -> PyTree:
     """Write the participants' fresh ν̄⁽ⁱ⁾ rows into the population-sized
@@ -298,6 +306,25 @@ SELECTORS: dict[str, Callable] = {
 }
 
 
+@jax.named_scope("fed.orientation")
+def corrections(nu: PyTree, nu_i: PyTree,
+                ids: Optional[jax.Array] = None) -> PyTree:
+    """The clients' calibration c⁽ⁱ⁾ = ν − ν⁽ⁱ⁾, one row per client;
+    ``ids`` picks the cohort's rows of a population-sized ν⁽ⁱ⁾."""
+    def one(n, ni):
+        if not ni.ndim:
+            return n - ni
+        return n[None] - (ni if ids is None else ni[ids])
+    return jax.tree.map(one, nu, nu_i)
+
+
+@jax.named_scope("fed.orientation")
+def transmit_mix(weights: jax.Array, transmit: PyTree) -> PyTree:
+    """Σ_i w_i · transmit⁽ⁱ⁾: the new global ν of a synchronous round, or
+    what a cohort or a buffer contributes to it."""
+    return tree_wsum(weights, transmit)
+
+
 def fast_mask(kf: jax.Array, kbar: jax.Array) -> jax.Array:
     """K_i > K̄ with a tie tolerance: K_i are integers (spacing 1) but K̄ is
     an f32 dot whose summation ORDER can leave it 1 ulp under an exact tie —
@@ -331,6 +358,7 @@ def recover_avg_grad(params0: PyTree, x_i: PyTree, c_all: PyTree,
         anchor_i, x_i, c_all)
 
 
+@jax.named_scope("fed.orientation")
 def orientation_transmit(algo: Algorithm, params0: PyTree, x_i: PyTree,
                          g0_i: PyTree, acc_i: PyTree, c_all: PyTree,
                          kf: jax.Array, kbar: jax.Array, lr: float, lam, *,
@@ -406,6 +434,7 @@ SERVER_OPTIMIZERS: dict[str, Callable] = {
 }
 
 
+@jax.named_scope("fed.aggregate")
 def server_update(algo: Algorithm, state: dict, params0: PyTree,
                   agg: PyTree, new_state: dict) -> PyTree:
     """FedOpt server step on the round pseudo-gradient Δ = agg − x̃_t."""
@@ -497,8 +526,7 @@ def make_layered_round(loss_fn: Callable[[PyTree, PyTree], jax.Array],
             nu_bc = state["nu"] if algo.uses_nu else None
 
         if algo.uses_nu:
-            c_all = jax.tree.map(lambda nu, nui: (nu[None] - nui) if nui.ndim
-                                 else nu - nui, nu_bc, state["nu_i"])
+            c_all = corrections(nu_bc, state["nu_i"])
         else:
             c_all = zero_corrections(params0, m)
 
@@ -561,7 +589,7 @@ def make_layered_round(loss_fn: Callable[[PyTree, PyTree], jax.Array],
                                          state["round"],
                                          jnp.arange(m, dtype=jnp.int32))
                 transmit = _urr(t_rows)
-            new_state["nu"] = constrain(tree_wsum(w_nu, transmit), 0)
+            new_state["nu"] = constrain(transmit_mix(w_nu, transmit), 0)
             # Line 11: the *local* reference ν⁽ⁱ⁾ is always the averaged grad
             new_state["nu_i"] = constrain(avg_g, 1)
 
@@ -659,9 +687,7 @@ def make_cohort_round(loss_fn: Callable[[PyTree, PyTree], jax.Array],
 
         if algo.uses_nu:
             # gather only the cohort's correction rows: compute is O(C)
-            c_all = jax.tree.map(
-                lambda nu, nui: (nu[None] - nui[cohort]) if nui.ndim
-                else nu - nui, nu_bc, state["nu_i"])
+            c_all = corrections(nu_bc, state["nu_i"], cohort)
         else:
             c_all = zero_corrections(params0, c)
 
@@ -717,7 +743,7 @@ def make_cohort_round(loss_fn: Callable[[PyTree, PyTree], jax.Array],
                     t_rows, w_nu = rb.nu(t_rows, cweights, state,
                                          state["round"], cohort)
                 transmit = _urr(t_rows)
-            contrib = tree_wsum(w_nu, transmit)
+            contrib = transmit_mix(w_nu, transmit)
             new_nu = nu_mass_mix(state["nu"], contrib, mass)
             new_state["nu"] = constrain(new_nu, 0)
             new_state["nu_i"] = constrain(

@@ -41,7 +41,6 @@ instead of the buffer.
 """
 from __future__ import annotations
 
-import time
 import warnings
 from typing import Any, Callable, Optional
 
@@ -52,7 +51,6 @@ import numpy as np
 from repro.configs.base import FedConfig
 from repro.core import compress, flat, robust, rounds, stages
 from repro.core.fedopt import get_algorithm
-from repro.core.tree_util import tree_wsum
 from repro.data.partition import gaussian_k_schedule
 from repro.fed.clock import ClientClock, Timeline, make_clock, \
     simulate_timeline
@@ -421,7 +419,7 @@ class BufferedAsyncSimulation:
                 # ν renorm preserves Σw̃ so the mass-mix ρ keeps its
                 # planned value; an all-dropped buffer contributes 0 and
                 # ν decays by (1 − ρ) — a safe fade, never a poisoned mix
-                contrib = tree_wsum(w_nu, transmit)
+                contrib = stages.transmit_mix(w_nu, transmit)
                 new_state["nu"] = stages.nu_mass_mix(state["nu"], contrib,
                                                      mass)
                 if rb is not None:
@@ -598,20 +596,16 @@ class BufferedAsyncSimulation:
             else:
                 xs["batches"] = self._host_batches(tl, u, r)
             fn = self._chunk_fn()
-            tic = time.perf_counter()
             carry, metrics = fn((self.state, self._anchors,
                                  self._nu_anchors), xs)
             self.state, self._anchors, self._nu_anchors = carry
-            # timed region covers the compute, not the async dispatch
             jax.block_until_ready(self.state)
-            dt = time.perf_counter() - tic
             hist.loss.extend(np.asarray(metrics["loss"],
                                         np.float64).tolist())
             hist.kbar.extend(np.asarray(metrics["kbar"],
                                         np.float64).tolist())
             hist.mass.extend(np.asarray(metrics["mass"],
                                         np.float64).tolist())
-            hist.wall.extend([dt / r] * r)
             hist.sim_time.extend(tl.arrival_t[sl, -1].tolist())
             hist.staleness.extend(tau[sl].mean(axis=1).tolist())
             # wire traffic per update: B reports up, B re-dispatch

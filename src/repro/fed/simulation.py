@@ -17,7 +17,6 @@ into a single transfer."""
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
 from typing import Any, Callable, Optional
 
@@ -51,7 +50,6 @@ class History:
     loss: list[float] = dataclasses.field(default_factory=list)
     metric: list[float] = dataclasses.field(default_factory=list)
     kbar: list[float] = dataclasses.field(default_factory=list)
-    wall: list[float] = dataclasses.field(default_factory=list)
     per_client: list[list[float]] = dataclasses.field(default_factory=list)
     # buffered-async engine (fed/async_engine.py): simulated arrival time of
     # each server update, the mean staleness of its buffer, and the buffer
@@ -360,13 +358,9 @@ class FederatedSimulation:
         k_t = (jnp.asarray(self.k_schedule[t % len(self.k_schedule)])
                if self.scenario is None else jnp.asarray(self._k_row(t)))
         batches = self.batcher.round_batches(t, self.k_max)
-        t0 = time.perf_counter()
         self.state, metrics = round_fn(self.state, batches, k_t,
                                        self.weights, jnp.float32(lam))
-        # the timed region must cover the COMPUTE, not the async dispatch:
-        # without the block, hist.wall under-reports by the entire round
         jax.block_until_ready(self.state)
-        hist.wall.append(time.perf_counter() - t0)
         hist.loss.append(float(metrics["loss"]))
         hist.kbar.append(float(metrics["kbar"]))
         if "quarantined" in metrics:
@@ -375,21 +369,30 @@ class FederatedSimulation:
         self._record_bytes(hist, 1, self.fed.n_clients)
 
     def _run_chunk(self, t0: int, r: int, hist: History) -> None:
+        """One scanned chunk of r rounds, under host spans on the
+        profiler's clock: ``fed.chunk`` (a step, numbered by its first
+        round) holding ``fed.inputs``, ``fed.dispatch``, ``fed.wait`` and
+        ``fed.history``."""
         chunk_fn = self._chunk_fn(r)
-        batches, ks, weights, lams = self._chunk_inputs(t0, r)
-        tic = time.perf_counter()
-        self.state, metrics = chunk_fn(self.state, batches, ks, weights,
-                                       lams)
-        jax.block_until_ready(self.state)
-        dt = time.perf_counter() - tic
-        hist.loss.extend(np.asarray(metrics["loss"], np.float64).tolist())
-        hist.kbar.extend(np.asarray(metrics["kbar"], np.float64).tolist())
-        if "quarantined" in metrics:
-            hist.quarantined.extend(
-                np.asarray(metrics["quarantined"], np.float64).tolist())
-        hist.wall.extend([dt / r] * r)
-        self._record_dropped(hist, t0, r)
-        self._record_bytes(hist, r, self.fed.n_clients)
+        with jax.profiler.StepTraceAnnotation("fed.chunk", step_num=t0):
+            with jax.profiler.TraceAnnotation("fed.inputs"):
+                batches, ks, weights, lams = self._chunk_inputs(t0, r)
+            with jax.profiler.TraceAnnotation("fed.dispatch"):
+                self.state, metrics = chunk_fn(self.state, batches, ks,
+                                               weights, lams)
+            with jax.profiler.TraceAnnotation("fed.wait"):
+                jax.block_until_ready(self.state)
+            with jax.profiler.TraceAnnotation("fed.history"):
+                hist.loss.extend(
+                    np.asarray(metrics["loss"], np.float64).tolist())
+                hist.kbar.extend(
+                    np.asarray(metrics["kbar"], np.float64).tolist())
+                if "quarantined" in metrics:
+                    hist.quarantined.extend(
+                        np.asarray(metrics["quarantined"],
+                                   np.float64).tolist())
+                self._record_dropped(hist, t0, r)
+                self._record_bytes(hist, r, self.fed.n_clients)
 
     def lower_chunk(self, r: int, t0: int = 0) -> jax.stages.Lowered:
         """The r-round chunk ``run`` dispatches for rounds t0…t0+r-1,
@@ -423,13 +426,11 @@ class FederatedSimulation:
                 jnp.int32(t), jnp.asarray(ids, jnp.int32), self.k_max)
         else:
             batches = self.batcher.cohort_batches(t, ids, self.k_max)
-        t0 = time.perf_counter()
         self.state, metrics = fn(self.state, batches,
                                  jnp.asarray(ids, jnp.int32),
                                  jnp.asarray(k_c, jnp.int32),
                                  jnp.asarray(cw), jnp.float32(lam))
         jax.block_until_ready(self.state)
-        hist.wall.append(time.perf_counter() - t0)
         hist.loss.append(float(metrics["loss"]))
         hist.kbar.append(float(metrics["kbar"]))
         hist.mass.append(float(metrics["mass"]))
@@ -470,17 +471,14 @@ class FederatedSimulation:
                                                         self.k_max)
             args = (batches, jnp.asarray(cohorts, jnp.int32),
                     jnp.asarray(ks), jnp.asarray(cws), lams)
-        tic = time.perf_counter()
         self.state, metrics = chunk_fn(self.state, *args)
         jax.block_until_ready(self.state)
-        dt = time.perf_counter() - tic
         hist.loss.extend(np.asarray(metrics["loss"], np.float64).tolist())
         hist.kbar.extend(np.asarray(metrics["kbar"], np.float64).tolist())
         hist.mass.extend(np.asarray(metrics["mass"], np.float64).tolist())
         if "quarantined" in metrics:
             hist.quarantined.extend(
                 np.asarray(metrics["quarantined"], np.float64).tolist())
-        hist.wall.extend([dt / r] * r)
         self._record_dropped(hist, t0, r)
         self._record_bytes(hist, r, self.population.cohort_size)
 

@@ -67,9 +67,10 @@ def apply_block(params: Params, kind: str, x: jax.Array, cfg: ModelConfig, *,
     if kind in ATTN_KINDS:
         h = apply_norm(params["norm1"], x, cfg.norm, cfg.norm_eps)
         is_global = kind != "attn_local" if cfg.sliding_window else True
-        a, new_cache = attn_mod.attention(
-            params["attn"], h, cfg, angles=angles, q_pos=q_pos,
-            is_global=is_global, cache=cache, seq_shard=seq_shard)
+        with jax.named_scope("model.attention"):
+            a, new_cache = attn_mod.attention(
+                params["attn"], h, cfg, angles=angles, q_pos=q_pos,
+                is_global=is_global, cache=cache, seq_shard=seq_shard)
         x = x + a
         h = apply_norm(params["norm2"], x, cfg.norm, cfg.norm_eps)
         if cfg.moe is not None:

@@ -106,6 +106,7 @@ def _row_positions(B: int, S: int, pos_offset) -> jax.Array:
     return off[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
 
 
+@jax.named_scope("model.embed")
 def _embed(params: Params, batch: dict, cfg: ModelConfig, pos_offset):
     if cfg.frontend == "vision":
         h = batch["embeds"].astype(jnp.dtype(cfg.dtype))
@@ -176,16 +177,17 @@ def forward(params: Params, batch: dict, cfg: ModelConfig, *,
     (h, aux), new_caches = jax.lax.scan(group_fn, (h, aux0),
                                         (params["segments"], seg_caches))
 
-    h = apply_norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
-    if last_only:
-        h = h[:, -1:]
-    if cfg.frontend == "audio":
-        logits = jnp.einsum("bsd,kdv->bskv", h, params["heads"])
-    elif cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", h, params["embed"])
-    else:
-        logits = jnp.einsum("bsd,dv->bsv", h, params["head"])
-    logits = constrain(logits, "dp", None, "mp")
+    with jax.named_scope("model.head"):
+        h = apply_norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
+        if last_only:
+            h = h[:, -1:]
+        if cfg.frontend == "audio":
+            logits = jnp.einsum("bsd,kdv->bskv", h, params["heads"])
+        elif cfg.tie_embeddings:
+            logits = jnp.einsum("bsd,vd->bsv", h, params["embed"])
+        else:
+            logits = jnp.einsum("bsd,dv->bsv", h, params["head"])
+        logits = constrain(logits, "dp", None, "mp")
     return logits, (new_caches if caches is not None else None), aux
 
 
@@ -193,6 +195,7 @@ def forward(params: Params, batch: dict, cfg: ModelConfig, *,
 # losses / steps
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("model.loss")
 def cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
     """logits (..., V) fp-any; labels (...) int32.
 

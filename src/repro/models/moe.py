@@ -36,6 +36,7 @@ def init_moe(key, cfg: ModelConfig, dtype) -> dict:
     return p
 
 
+@jax.named_scope("moe.route")
 def route(router_w: jax.Array, x: jax.Array, top_k: int):
     """x (T, d) -> (weights (T,k), ids (T,k), aux_loss, router_probs)."""
     logits = jnp.einsum("td,de->te", x.astype(jnp.float32), router_w)
@@ -63,40 +64,46 @@ def moe(params: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.Ar
 
     weights, ids, aux = route(params["router"], xt, k)        # (T,k)
 
-    flat_ids = ids.reshape(-1)                                # (T*k,)
-    order = jnp.argsort(flat_ids)                             # stable
-    sorted_ids = flat_ids[order]
-    # position of each assignment within its expert's queue
-    pos_in_expert = jnp.arange(T * k) - jnp.searchsorted(sorted_ids,
-                                                         sorted_ids, side="left")
     capacity = int(max(1, round(T * k / E * m.capacity_factor)))
-    keep = pos_in_expert < capacity
+    with jax.named_scope("moe.dispatch"):
+        flat_ids = ids.reshape(-1)                            # (T*k,)
+        order = jnp.argsort(flat_ids)                         # stable
+        sorted_ids = flat_ids[order]
+        # position of each assignment within its expert's queue
+        pos_in_expert = jnp.arange(T * k) - jnp.searchsorted(
+            sorted_ids, sorted_ids, side="left")
+        keep = pos_in_expert < capacity
 
-    token_of = order // k                                     # source token
-    dst = jnp.where(keep, sorted_ids * capacity + pos_in_expert, E * capacity)
+        token_of = order // k                                 # source token
+        dst = jnp.where(keep, sorted_ids * capacity + pos_in_expert,
+                        E * capacity)
 
-    # scatter tokens into (E*C, d) buffers (row E*C is a dropped-token sink)
-    buf = jnp.zeros((E * capacity + 1, d), x.dtype)
-    buf = buf.at[dst].set(xt[token_of], mode="drop")
-    buf = buf[: E * capacity].reshape(E, capacity, d)
-    buf = constrain(buf, "mp", None, None)                    # all-to-all here
+        # scatter tokens into (E*C, d) buffers (row E*C is a dropped-token
+        # sink)
+        buf = jnp.zeros((E * capacity + 1, d), x.dtype)
+        buf = buf.at[dst].set(xt[token_of], mode="drop")
+        buf = buf[: E * capacity].reshape(E, capacity, d)
+        buf = constrain(buf, "mp", None, None)                # all-to-all here
 
-    h = jnp.einsum("ecd,edf->ecf", buf, params["w_in"])
-    if cfg.glu:
-        g = jnp.einsum("ecd,edf->ecf", buf, params["w_gate"])
-        h = activation(g, cfg.act) * h
-    else:
-        h = activation(h, cfg.act)
-    out = jnp.einsum("ecf,efd->ecd", h, params["w_out"])      # (E,C,d)
-    out = constrain(out, "mp", None, None)
-    out_flat = jnp.concatenate(
-        [out.reshape(E * capacity, d), jnp.zeros((1, d), out.dtype)], axis=0)
+    with jax.named_scope("moe.experts"):
+        h = jnp.einsum("ecd,edf->ecf", buf, params["w_in"])
+        if cfg.glu:
+            g = jnp.einsum("ecd,edf->ecf", buf, params["w_gate"])
+            h = activation(g, cfg.act) * h
+        else:
+            h = activation(h, cfg.act)
+        out = jnp.einsum("ecf,efd->ecd", h, params["w_out"])  # (E,C,d)
+        out = constrain(out, "mp", None, None)
 
-    # gather back: assignment j of token t reads row dst[inv_order[t*k+j]]
-    inv = jnp.zeros_like(order).at[order].set(jnp.arange(T * k))
-    rows = out_flat[dst[inv]].reshape(T, k, d)
-    y = jnp.einsum("tkd,tk->td", rows.astype(jnp.float32),
-                   weights.astype(jnp.float32)).astype(x.dtype)
+    with jax.named_scope("moe.combine"):
+        out_flat = jnp.concatenate(
+            [out.reshape(E * capacity, d), jnp.zeros((1, d), out.dtype)],
+            axis=0)
+        # gather back: assignment j of token t reads row dst[inv_order[t*k+j]]
+        inv = jnp.zeros_like(order).at[order].set(jnp.arange(T * k))
+        rows = out_flat[dst[inv]].reshape(T, k, d)
+        y = jnp.einsum("tkd,tk->td", rows.astype(jnp.float32),
+                       weights.astype(jnp.float32)).astype(x.dtype)
 
     if m.n_shared_experts:
         y = y + mlp(params["shared"], x, cfg).reshape(T, d)
