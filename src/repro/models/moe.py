@@ -1,16 +1,26 @@
 """Mixture-of-Experts with top-k routing.
 
 Dispatch is *sort-based with a capacity limit* (honest active-FLOPs: no dense
-one-hot matmuls): token→expert assignments are argsorted by expert id, each
-expert processes a fixed-capacity (E, C, d) buffer, and outputs are combined
-by gather + weighted sum.  Expert weights carry an expert axis sharded over
-``mp`` — the (E, C, d) buffers are sharding-constrained on that axis so the
-SPMD partitioner inserts the all-to-alls.
+one-hot matmuls): each expert processes a fixed-capacity (E, C, d) buffer
+that holds the first C of its assignments in flat order (token-major), and
+outputs are combined by a weighted sum.  Every index map is dense
+arithmetic (``Routing``): ranks from a cumulative sum of the one-hot expert
+ids, expert order from one stable argsort.  Rows move by gathers only: into
+the buffers, one gather of the T*k assignments in expert order cut into
+contiguous per-expert windows; back, one gather of each assignment's row.
+Each is the other's transpose (``_dispatch``, ``_combine``), so no pass,
+forward or backward, scatters.  Expert weights carry an expert axis sharded
+over ``mp`` — the (E, C, d) buffers are sharding-constrained on that axis
+so the SPMD partitioner inserts the all-to-alls.
 """
 from __future__ import annotations
 
+import functools
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.dist import constrain
@@ -36,12 +46,33 @@ def init_moe(key, cfg: ModelConfig, dtype) -> dict:
     return p
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _top_k(probs: jax.Array, k: int):
+    """``lax.top_k``, whose values transpose by a one-hot product rather
+    than by a scatter-add."""
+    return jax.lax.top_k(probs, k)
+
+
+def _top_k_fwd(probs, k):
+    out = jax.lax.top_k(probs, k)
+    chosen = out[1][..., None] == jnp.arange(probs.shape[-1])  # (..., k, E)
+    return out, chosen
+
+
+def _top_k_bwd(k, chosen, cts):
+    d_top_p, _ = cts
+    return (jnp.sum(jnp.where(chosen, d_top_p[..., None], 0), axis=-2),)
+
+
+_top_k.defvjp(_top_k_fwd, _top_k_bwd)
+
+
 @jax.named_scope("moe.route")
 def route(router_w: jax.Array, x: jax.Array, top_k: int):
-    """x (T, d) -> (weights (T,k), ids (T,k), aux_loss, router_probs)."""
+    """x (T, d) -> (weights (T,k), ids (T,k), aux_loss)."""
     logits = jnp.einsum("td,de->te", x.astype(jnp.float32), router_w)
     probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_ids = jax.lax.top_k(probs, top_k)
+    top_p, top_ids = _top_k(probs, top_k)
     top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     # switch-style load-balance aux loss
     E = router_w.shape[-1]
@@ -50,6 +81,80 @@ def route(router_w: jax.Array, x: jax.Array, top_k: int):
     ce = jnp.mean(one_hot, axis=0)                            # token fraction / expert
     aux = E * jnp.sum(me * ce)
     return top_p, top_ids, aux
+
+
+class Routing(NamedTuple):
+    """Where each assignment (token t's j-th choice, flat index t*k + j)
+    goes in the (E, C, d) buffers, and where each buffer row comes from."""
+    ids: jax.Array     # (T, k) expert of each assignment
+    pos: jax.Array     # (T, k) its row in the expert's buffer; C if dropped
+    starts: jax.Array  # (E,) each expert's first position in expert order
+    counts: jax.Array  # (E,) assignments per expert
+    order: jax.Array   # (T*k + C,) assignments in expert order (stable),
+    #                    then C entries past the end
+
+
+def _to_experts(x: jax.Array, src: jax.Array, r: Routing) -> jax.Array:
+    """(E, C, d) buffers: the rows ``x[src]`` in expert order, cut into one
+    window of C rows per expert from its start, rows past the expert's
+    count zeroed.  One row gather, then contiguous windows.  The C entries
+    of ``src`` past the end only ever land past some expert's count, so
+    they may read any row (clipped)."""
+    capacity = r.order.shape[0] - r.ids.size
+    rows = jnp.take(x, src, axis=0, mode="clip")
+    win = jax.vmap(lambda s: jax.lax.dynamic_slice_in_dim(
+        rows, s, capacity))(r.starts)
+    live = jnp.arange(capacity) < r.counts[:, None]
+    return jnp.where(live[..., None], win, jnp.zeros((), x.dtype))
+
+
+def _from_experts(buf: jax.Array, r: Routing) -> jax.Array:
+    """(T, k, d): each assignment's row of ``buf``; zeros where dropped."""
+    return buf.at[r.ids, r.pos].get(mode="fill", fill_value=0)
+
+
+def _int_cotangent(r: Routing) -> Routing:
+    return Routing(*(np.zeros(a.shape, jax.dtypes.float0) for a in r))
+
+
+@jax.custom_vjp
+def _dispatch(xt: jax.Array, r: Routing) -> jax.Array:
+    """(T, d) tokens -> (E, C, d) buffers; the transpose gathers too."""
+    return _to_experts(xt, r.order // r.ids.shape[1], r)
+
+
+def _dispatch_fwd(xt, r):
+    return _dispatch(xt, r), r
+
+
+def _dispatch_bwd(r, dbuf):
+    with jax.named_scope("moe.dispatch"):
+        d_rows = _from_experts(dbuf, r).astype(jnp.float32)
+        dxt = jnp.sum(d_rows, axis=1).astype(dbuf.dtype)
+    return dxt, _int_cotangent(r)
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(out: jax.Array, r: Routing) -> jax.Array:
+    """(E, C, d) expert outputs -> (T, k, d) rows; the transpose gathers
+    too."""
+    return _from_experts(out, r)
+
+
+def _combine_fwd(out, r):
+    return _combine(out, r), r
+
+
+def _combine_bwd(r, d_rows):
+    with jax.named_scope("moe.combine"):
+        d_out = _to_experts(d_rows.reshape(-1, d_rows.shape[-1]), r.order, r)
+    return d_out, _int_cotangent(r)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def moe(params: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.Array]:
@@ -66,24 +171,17 @@ def moe(params: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.Ar
 
     capacity = int(max(1, round(T * k / E * m.capacity_factor)))
     with jax.named_scope("moe.dispatch"):
-        flat_ids = ids.reshape(-1)                            # (T*k,)
-        order = jnp.argsort(flat_ids)                         # stable
-        sorted_ids = flat_ids[order]
-        # position of each assignment within its expert's queue
-        pos_in_expert = jnp.arange(T * k) - jnp.searchsorted(
-            sorted_ids, sorted_ids, side="left")
-        keep = pos_in_expert < capacity
-
-        token_of = order // k                                 # source token
-        dst = jnp.where(keep, sorted_ids * capacity + pos_in_expert,
-                        E * capacity)
-
-        # scatter tokens into (E*C, d) buffers (row E*C is a dropped-token
-        # sink)
-        buf = jnp.zeros((E * capacity + 1, d), x.dtype)
-        buf = buf.at[dst].set(xt[token_of], mode="drop")
-        buf = buf[: E * capacity].reshape(E, capacity, d)
-        buf = constrain(buf, "mp", None, None)                # all-to-all here
+        # rank of each assignment within its expert, in flat order
+        onehot = (ids[..., None] == jnp.arange(E)).astype(jnp.int32)
+        onehot = onehot.reshape(T * k, E)
+        before = jnp.cumsum(onehot, axis=0) - onehot
+        pos = jnp.sum(before * onehot, axis=1).reshape(T, k)
+        counts = jnp.sum(onehot, axis=0)
+        order = jnp.concatenate([jnp.argsort(ids.reshape(-1)),
+                                 jnp.full((capacity,), T * k, jnp.int32)])
+        r = Routing(ids, jnp.minimum(pos, capacity),
+                    jnp.cumsum(counts) - counts, counts, order)
+        buf = constrain(_dispatch(xt, r), "mp", None, None)  # all-to-all here
 
     with jax.named_scope("moe.experts"):
         h = jnp.einsum("ecd,edf->ecf", buf, params["w_in"])
@@ -96,12 +194,7 @@ def moe(params: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.Ar
         out = constrain(out, "mp", None, None)
 
     with jax.named_scope("moe.combine"):
-        out_flat = jnp.concatenate(
-            [out.reshape(E * capacity, d), jnp.zeros((1, d), out.dtype)],
-            axis=0)
-        # gather back: assignment j of token t reads row dst[inv_order[t*k+j]]
-        inv = jnp.zeros_like(order).at[order].set(jnp.arange(T * k))
-        rows = out_flat[dst[inv]].reshape(T, k, d)
+        rows = _combine(out, r)                               # (T,k,d)
         y = jnp.einsum("tkd,tk->td", rows.astype(jnp.float32),
                        weights.astype(jnp.float32)).astype(x.dtype)
 
