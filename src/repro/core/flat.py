@@ -15,10 +15,12 @@ buffer and runs the *entire* round on flat state:
   ``P = ceil(n / 128) · 128`` so the matrices feed the Pallas kernels
   directly (``kernel.LANES`` lane padding, zeros in the tail — every stage
   below is padding-preserving, so the tail stays exactly zero);
-* the client k-step scan calls ``calibrated_update_2d`` /
-  ``calibrated_update_prox_2d`` once per local step on the whole ``(M, P)``
-  matrix — one fused launch instead of ``num_leaves`` tree_map dispatches —
-  with the K_i masking and ν/g₀ accumulation as flat row ops;
+* the client update calls ``calibrated_update_2d`` /
+  ``calibrated_update_prox_2d`` once per local step on flat rows — one
+  fused launch instead of ``num_leaves`` tree_map dispatches — with the
+  ν/g₀ accumulation as flat row ops; each client runs exactly its own K_i
+  steps where the rows sit on one device, a masked k_max-step scan where
+  they are spread over the mesh;
 * aggregation, orientation, ν mass-mix and the server optimizer REUSE the
   stage registries verbatim: the stage functions are pytree-polymorphic
   (``jax.tree.map`` over a bare array is the identity traversal), so a
@@ -57,6 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import dist
 from repro.core import compress, stages
 from repro.core import robust as robust_mod
 from repro.core.fedopt import Algorithm
@@ -340,126 +343,182 @@ def make_flat_client_update(spec: FlatSpec,
                             interpret: Optional[bool] = None,
                             per_client_anchor: bool = False):
     """Flat analogue of ``stages.make_client_update``: ``f(anchor, c_all,
-    batches, k_steps, lam) -> (x_i, g0_i, acc_i, loss0)`` on (M, P) rows.
-    ``c_all`` is ignored for algorithms without ν.
+    batches, k_steps, lam) -> (x_i, g0_i, acc_i, loss0, client_steps)`` on
+    (M, P) rows, ``client_steps`` the local steps computed.  ``c_all`` is
+    ignored for algorithms without ν.
 
-    The k-scan runs directly on the (M, P) matrix and each local step is
-    ONE fused calibrated-update launch instead of ``num_leaves`` tree_map
-    dispatches — the Pallas kernel on TPU (``use_pallas``), its jnp
-    oracle with the K_i mask folded in as a per-row step size elsewhere
-    (interpret-mode Pallas lowers to ~19 HLO ops of grid bookkeeping,
-    pure overhead inside a scanned round).  The per-step loss boundary is
-    flat-native: ``flat_value_and_grad`` evaluates the loss on view-table
-    slices of the row and returns the gradient as a (P,) cotangent buffer
-    — the tree exists only inside the loss jaxpr (DESIGN.md §13).
+    Each local step is ONE fused calibrated-update launch on a flat row
+    instead of ``num_leaves`` tree_map dispatches — the Pallas kernel on
+    TPU (``use_pallas``), its jnp oracle elsewhere (interpret-mode Pallas
+    lowers to ~19 HLO ops of grid bookkeeping, pure overhead inside a
+    loop).  The per-step loss boundary is flat-native:
+    ``flat_value_and_grad`` evaluates the loss on view-table slices of the
+    row and returns the gradient as a (P,) cotangent buffer — the tree
+    exists only inside the loss jaxpr (DESIGN.md §13).
 
-    The per-row η mask doubles as the **effective-steps mask** of
-    partial-work recovery (fed/scenarios.py, DESIGN.md §12): a mid-round
-    dropout's k′ < K_i arrives as ``k_steps`` and rows past the abort get
-    η = 0 — the flat path needs no separate fault machinery, matching the
-    tree path's scan-length mask bit-for-bit at the same k′.
+    Step asynchronism takes one of two forms, chosen from where the client
+    rows live (DESIGN.md §3):
+
+    * rows on one device: the clients run one after another, each a
+      ``while_loop`` of exactly its own K_i steps — Σ K_i client-steps a
+      round, nothing computed and thrown away, no mask;
+    * rows spread over the mesh's data axes (``dist.axis_size("cl") > 1``,
+      the SPMD launch round): a ``k_max``-step scan vmapped over the
+      clients, rows past their K_i masked — M · k_max client-steps, the
+      devices running their clients in parallel.
+
+    Either way a mid-round dropout's k′ < K_i (partial-work recovery,
+    fed/scenarios.py, DESIGN.md §12) arrives as ``k_steps``: it bounds the
+    client's loop, or masks its row past k′ — the flat path needs no
+    separate fault machinery.
     """
     use_pallas = _use_pallas_default(use_pallas)
     needs_first = algo.selector in ("fedagrac", "first", "reverse")
     uses_nu = algo.uses_nu
+    explicit_nu = track_nu == "explicit" and uses_nu
     # the tree path adds the prox term into g BEFORE the g₀ select and the
     # explicit-ν accumulation (stages.make_client_update); when either
     # consumer exists the flat path must augment g the same way and use
     # the PLAIN update — fusing prox into the kernel is only valid when
     # nothing downstream reads the gradient (the FedProx-style baselines)
-    fuse_prox = bool(algo.prox_mu) and not (
-        needs_first or (track_nu == "explicit" and uses_nu))
-
+    fuse_prox = bool(algo.prox_mu) and not (needs_first or explicit_nu)
     if use_pallas:
         interpret = not backend.on_tpu() if interpret is None else interpret
 
-        @jax.named_scope("fed.local_step")
-        def masked_update(x, g, c, anchors, k, k_steps, lam):
-            if fuse_prox:
-                upd = calibrated_update_prox_2d(x, g, c, anchors, lr, lam,
-                                                algo.prox_mu,
-                                                interpret=interpret)
-            else:
-                upd = calibrated_update_2d(x, g, c, lr, lam,
-                                           interpret=interpret)
-            return jnp.where((k < k_steps)[:, None], upd, x)
-    else:
-        @jax.named_scope("fed.local_step")
-        def masked_update(x, g, c, anchors, k, k_steps, lam):
-            """Oracle with the K_i mask FOLDED into the update as a
-            per-row step size η_i ∈ {η, 0}: an inactive row computes
-            x − 0·(…) = x exactly (finite operands), so the separate
-            (M, P) select — one extra full-state write per local step —
-            disappears.  Same f32-internal arithmetic as the kernel."""
-            eta = jnp.where(k < k_steps, jnp.float32(lr), 0.0)[:, None]
-            xf = x.astype(jnp.float32)
-            t = g.astype(jnp.float32)
-            if uses_nu:
-                t = t + lam * c.astype(jnp.float32)
-            if fuse_prox:
-                t = t + algo.prox_mu * (xf - anchors.astype(jnp.float32))
-            return (xf - eta * t).astype(x.dtype)
+    def update(x, g, c, anchors, eta, lam):
+        """``x − η(g + λc [+ μ(x − x₀)])`` on flat rows, ``eta`` a scalar
+        or one step size per row (η_i = 0 leaves row i exactly as it is,
+        for finite operands); f32 arithmetic, one rounding to the row
+        dtype — the kernel's."""
+        xf = x.astype(jnp.float32)
+        t = g.astype(jnp.float32)
+        if uses_nu:
+            t = t + lam * c.astype(jnp.float32)
+        if fuse_prox:
+            t = t + algo.prox_mu * (xf - anchors.astype(jnp.float32))
+        return (xf - eta * t).astype(x.dtype)
+
+    @jax.named_scope("fed.local_step")
+    def local_step(x, g, c, anchor, lam):
+        """One client's unmasked step on its (P,) row.  The kernel sees
+        the row as (P / 128, 128) — a bitcast of the contiguous row whose
+        (512, 128) tiles stream whole, where a (1, P) row would pad every
+        tile to 8 sublanes."""
+        if not use_pallas:
+            return update(x, g, c, anchor, jnp.float32(lr), lam)
+        rows = lambda a: a.reshape(-1, LANES)
+        if fuse_prox:
+            out = calibrated_update_prox_2d(rows(x), rows(g), rows(c),
+                                            rows(anchor), lr, lam,
+                                            algo.prox_mu,
+                                            interpret=interpret)
+        else:
+            out = calibrated_update_2d(rows(x), rows(g), rows(c), lr, lam,
+                                       interpret=interpret)
+        return out.reshape(x.shape)
+
+    @jax.named_scope("fed.local_step")
+    def masked_step(x, g, c, anchors, k, k_steps, lam):
+        """All (M, P) rows at step k, rows with k ≥ K_i left unchanged: the
+        mask folds into a per-row step size η_i ∈ {η, 0}, no separate
+        (M, P) select.  Always the oracle — the rows are spread over the
+        mesh here, and XLA cannot partition a Mosaic call."""
+        eta = jnp.where(k < k_steps, jnp.float32(lr), 0.0)[:, None]
+        return update(x, g, c, anchors, eta, lam)
 
     # flat-native loss boundary (DESIGN.md §13): losses on buffer VIEWS,
-    # gradients straight back as (M, P) cotangent rows — the round never
+    # gradients straight back as (P,) cotangent rows — the round never
     # holds the parameter tree, and under master_dtype mixed precision the
     # view cast is the only master→compute crossing
-    grad_fn = jax.vmap(flat_value_and_grad(spec, loss_fn))
+    grad_row = flat_value_and_grad(spec, loss_fn)
 
-    @jax.named_scope("fed.client_update")
-    def run(anchor, c_all, batches, k_steps, lam):
+    def serial(anchor, c_k, batches, k_steps, lam_k):
+        """Clients in turn (an outer scan over the rows), each running a
+        while_loop of exactly min(K_i, k_max) steps."""
+        zero_row = jnp.zeros((spec.p,), spec.dtype)
+
+        def client(_, xs):
+            anchor_i = xs["anchor"] if per_client_anchor else anchor
+            c_i = xs["c"] if uses_nu else zero_row
+            batch_i, k_i = xs["batch"], xs["k"]
+            w = 1.0 / k_i.astype(jnp.float32)
+
+            def step(carry):
+                k, x, g0, acc, loss0 = carry
+                batch = jax.tree.map(
+                    lambda b: jax.lax.dynamic_index_in_dim(b, k, 0, False),
+                    batch_i)
+                loss, g = grad_row(x, batch)
+                if algo.prox_mu and not fuse_prox:
+                    g = g + algo.prox_mu * (x - anchor_i)
+                x = local_step(x, g, c_i, anchor_i, lam_k)
+                first = k == 0
+                loss0 = jnp.where(first, loss.astype(jnp.float32), loss0)
+                if needs_first:
+                    g0 = jax.lax.cond(first, lambda: g, lambda: g0)
+                if explicit_nu:
+                    acc = acc + w * g
+                return k + 1, x, g0, acc, loss0
+
+            n_i = jnp.minimum(k_i, k_max)
+            carry = (jnp.int32(0), anchor_i,
+                     zero_row if needs_first else jnp.zeros(()),
+                     zero_row if explicit_nu else jnp.zeros(()),
+                     jnp.float32(0.0))
+            k, x, g0, acc, loss0 = jax.lax.while_loop(
+                lambda c: c[0] < n_i, step, carry)
+            return None, (x, g0, acc, loss0, k)
+
+        xs = {"batch": batches, "k": k_steps}
+        if per_client_anchor:
+            xs["anchor"] = anchor
+        if uses_nu:
+            xs["c"] = c_k
+        _, (x, g0, acc, loss0, ran) = jax.lax.scan(client, None, xs)
+        return (x, g0 if needs_first else jnp.zeros(()),
+                acc if explicit_nu else jnp.zeros(()), loss0, jnp.sum(ran))
+
+    grad_rows = jax.vmap(grad_row)
+
+    def masked(anchor, c_k, batches, k_steps, lam_k):
+        """The k_max-step scan over the whole client axis, rows past their
+        K_i masked (same order the vmapped tree scan lowers to)."""
         m = k_steps.shape[0]
         anchors = (anchor if per_client_anchor
                    else jnp.broadcast_to(anchor[None], (m, spec.p)))
-        # λ multiplies a zero c for ν-free algorithms — bake λ = 0 so the
-        # kernel's λ·c term vanishes exactly (x − η(g + 0) ≡ x − ηg)
-        lam_k = lam if uses_nu else 0.0
-        c_k = (c_all if uses_nu
-               else jnp.zeros((m, spec.p), spec.dtype))
-        # (M, k_max, …) → (k_max, M, …): scan over local steps, whole
-        # client axis per step (same order the vmapped tree scan lowers to)
+        if not uses_nu:
+            c_k = jnp.zeros((m, spec.p), spec.dtype)
         bk = jax.tree.map(lambda b: jnp.swapaxes(b, 0, 1), batches)
 
         def step(carry, xs):
             k, batch_k = xs
-            x, g0, nu_acc = carry
-            loss, g = grad_fn(x, batch_k)
+            x, g0, acc = carry
+            loss, g = grad_rows(x, batch_k)
             if algo.prox_mu and not fuse_prox:
                 g = g + algo.prox_mu * (x - anchors)
-            x = masked_update(x, g, c_k, anchors, k, k_steps, lam_k)
+            x = masked_step(x, g, c_k, anchors, k, k_steps, lam_k)
             if needs_first:
                 g0 = jnp.where(k == 0, g, g0)
-            if track_nu == "explicit" and uses_nu:
+            if explicit_nu:
                 w = jnp.where(k < k_steps,
                               1.0 / k_steps.astype(jnp.float32), 0.0)
-                nu_acc = nu_acc + w[:, None] * g
-            return (x, g0, nu_acc), loss
+                acc = acc + w[:, None] * g
+            return (x, g0, acc), loss
 
-        if k_max == 1:
-            # single-local-step rounds (FedSGD-style comm-bound regime):
-            # no scan and no g₀ select — every client runs exactly its
-            # one step (K_i ≥ 1), g₀ IS the only gradient
-            b0 = jax.tree.map(lambda b: b[0], bk)
-            loss, g = grad_fn(anchors, b0)
-            # unfused prox needs no augmentation here: x ≡ x₀ at k = 0, so
-            # the prox term μ(x − x₀) is exactly zero (as on the tree path)
-            x = masked_update(anchors, g, c_k, anchors, jnp.int32(0),
-                              k_steps, lam_k)
-            g0 = g if needs_first else jnp.zeros(())
-            if track_nu == "explicit" and uses_nu:
-                w = 1.0 / k_steps.astype(jnp.float32)    # same rounding as
-                nu_acc = w[:, None] * g                  # the in-scan path
-            else:
-                nu_acc = jnp.zeros(())
-            return x, g0, nu_acc, loss
+        zeros = jnp.zeros((m, spec.p), spec.dtype)
+        (x, g0, acc), losses = jax.lax.scan(
+            step, (anchors, zeros if needs_first else jnp.zeros(()),
+                   zeros if explicit_nu else jnp.zeros(())),
+            (jnp.arange(k_max), bk))
+        return x, g0, acc, losses[0], jnp.int32(m * k_max)
 
-        g0_0 = (jnp.zeros((m, spec.p), spec.dtype) if needs_first
-                else jnp.zeros(()))
-        acc_0 = (jnp.zeros((m, spec.p), spec.dtype)
-                 if (track_nu == "explicit" and uses_nu) else jnp.zeros(()))
-        (x, g0, nu_acc), losses = jax.lax.scan(
-            step, (anchors, g0_0, acc_0), (jnp.arange(k_max), bk))
-        return x, g0, nu_acc, losses[0]
+    @jax.named_scope("fed.client_update")
+    def run(anchor, c_all, batches, k_steps, lam):
+        # λ multiplies a zero c for ν-free algorithms — bake λ = 0 so the
+        # kernel's λ·c term vanishes exactly (x − η(g + 0) ≡ x − ηg)
+        lam_k = lam if uses_nu else 0.0
+        path = masked if dist.axis_size("cl") > 1 else serial
+        return path(anchor, c_all, batches, k_steps, lam_k)
 
     return run
 
@@ -547,8 +606,8 @@ def make_flat_round(spec: FlatSpec,
         c_all = (stages.corrections(nu_bc, state["nu_i"])
                  if algo.uses_nu else None)                # (M, P)
 
-        x_i, g0_i, acc_i, loss0 = client_update(anchor, c_all, batches,
-                                                k_steps, lam)
+        x_i, g0_i, acc_i, loss0, client_steps = client_update(
+            anchor, c_all, batches, k_steps, lam)
         x_i = constrain(x_i, 1)
         kf = k_steps.astype(jnp.float32)
 
@@ -609,7 +668,8 @@ def make_flat_round(spec: FlatSpec,
                 new_state["nu_i"] = rb.guard(new_state["nu_i"],
                                              state["nu_i"])
 
-        metrics = {"loss": jnp.dot(weights, loss0), "kbar": kbar}
+        metrics = {"loss": jnp.dot(weights, loss0), "kbar": kbar,
+                   "client_steps": client_steps}
         if rb is not None:
             metrics["quarantined"] = qcount
         return new_state, metrics
@@ -674,8 +734,8 @@ def make_flat_cohort_round(spec: FlatSpec,
         c_all = (stages.corrections(nu_bc, state["nu_i"], cohort)
                  if algo.uses_nu else None)                # (C, P) rows
 
-        x_i, g0_i, acc_i, loss0 = client_update(anchor, c_all, batches,
-                                                k_steps, lam)
+        x_i, g0_i, acc_i, loss0, client_steps = client_update(
+            anchor, c_all, batches, k_steps, lam)
         x_i = constrain(x_i, 1)
 
         w_agg = cweights
@@ -730,7 +790,7 @@ def make_flat_cohort_round(spec: FlatSpec,
                                              state["nu_i"])
 
         metrics = {"loss": jnp.dot(cweights, loss0) / mass, "kbar": kbar,
-                   "mass": mass}
+                   "mass": mass, "client_steps": client_steps}
         if rb is not None:
             metrics["quarantined"] = qcount
         return new_state, metrics

@@ -8,9 +8,10 @@ annotate intermediates with logical names only:
     constrain(h, "dp", None, "mp")     # (batch, seq, hidden)
 
 Logical names: ``dp`` (batch/data parallel), ``mp`` (tensor/model parallel),
-``sp`` (sequence parallel — long-decode KV caches).  Outside any mesh (unit
-tests, CPU simulation) every call is a no-op, so the model zoo runs unchanged
-on a single device.
+``sp`` (sequence parallel — long-decode KV caches), ``cl`` (the flat round's
+client rows: spread over more than one device, its clients run in parallel,
+core/flat.py).  Outside any mesh (unit tests, CPU simulation) every call is
+a no-op, so the model zoo runs unchanged on a single device.
 
 Two deliberate behaviours (relied on by the model code):
 

@@ -298,9 +298,13 @@ class BufferedAsyncSimulation:
                 self._spec, self._loss_fn, algo, lr=lr, k_max=k_max,
                 per_client_anchor=True)
         else:
-            client_update = stages.make_client_update(
+            tree_update = stages.make_client_update(
                 self._loss_fn, algo, lr=lr, k_max=k_max,
                 per_client_anchor=True)
+
+            def client_update(*args):
+                # the tree scan computes k_max steps for every report
+                return (*tree_update(*args), jnp.int32(buffer * k_max))
         aggregate = stages.BUFFERED_AGGREGATORS[algo.aggregator]
         cs = compress.build_stages(self.compression, self._spec, uses_nu)
         down_on = cs is not None and cs.down is not None
@@ -363,8 +367,8 @@ class BufferedAsyncSimulation:
             else:
                 c_b = stages.zero_corrections(params, buffer)
 
-            x_b, g0_b, acc_b, loss0 = client_update(anchor_i, c_b, batches,
-                                                    k_steps, lam)
+            x_b, g0_b, acc_b, loss0, client_steps = client_update(
+                anchor_i, c_b, batches, k_steps, lam)
 
             # uplink wire path at the REPORTING ids: each reporter's
             # error-feedback row rides its own reports (a duplicate
@@ -471,7 +475,7 @@ class BufferedAsyncSimulation:
                     N = scatter(N, state["nu"], new_state["nu"])
 
             metrics = {"loss": jnp.dot(sw, loss0) / mass, "kbar": kbar,
-                       "mass": mass}
+                       "mass": mass, "client_steps": client_steps}
             if rb is not None:
                 metrics["quarantined"] = qcount
             return (new_state, A, N), metrics
@@ -600,12 +604,7 @@ class BufferedAsyncSimulation:
                                  self._nu_anchors), xs)
             self.state, self._anchors, self._nu_anchors = carry
             jax.block_until_ready(self.state)
-            hist.loss.extend(np.asarray(metrics["loss"],
-                                        np.float64).tolist())
-            hist.kbar.extend(np.asarray(metrics["kbar"],
-                                        np.float64).tolist())
-            hist.mass.extend(np.asarray(metrics["mass"],
-                                        np.float64).tolist())
+            hist.record(metrics)
             hist.sim_time.extend(tl.arrival_t[sl, -1].tolist())
             hist.staleness.extend(tau[sl].mean(axis=1).tolist())
             # wire traffic per update: B reports up, B re-dispatch
@@ -617,10 +616,6 @@ class BufferedAsyncSimulation:
             if self.scenario is not None:
                 hist.dropped.extend(
                     tl.aborted[sl].mean(axis=1).tolist())
-            if "quarantined" in metrics:
-                hist.quarantined.extend(
-                    np.asarray(metrics["quarantined"],
-                               np.float64).tolist())
             u += r
             if self.eval_fn is not None and u % eval_every == 0:
                 value = float(self.eval_fn(self.params))

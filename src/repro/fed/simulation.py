@@ -70,6 +70,19 @@ class History:
     # fp32 cost when compression is off, so baselines compare directly
     bytes_up: list[float] = dataclasses.field(default_factory=list)
     bytes_down: list[float] = dataclasses.field(default_factory=list)
+    # local steps each round/update computed (flat layout, core/flat.py):
+    # Σ K_i where the clients run in turn, M · k_max where the mesh spreads
+    # their rows; empty on the tree layout
+    client_steps: list[float] = dataclasses.field(default_factory=list)
+
+    def record(self, metrics: dict) -> None:
+        """Append one round's metrics, or a chunk's ``(r,)`` stacks: loss
+        and kbar, and mass, quarantined and client_steps where the round
+        reports them."""
+        for name in ("loss", "kbar", "mass", "quarantined", "client_steps"):
+            if name in metrics:
+                getattr(self, name).extend(np.atleast_1d(
+                    np.asarray(metrics[name], np.float64)).tolist())
 
     def fairness(self) -> Optional[dict]:
         """FL fairness of the final round: worst-client metric and the
@@ -361,10 +374,7 @@ class FederatedSimulation:
         self.state, metrics = round_fn(self.state, batches, k_t,
                                        self.weights, jnp.float32(lam))
         jax.block_until_ready(self.state)
-        hist.loss.append(float(metrics["loss"]))
-        hist.kbar.append(float(metrics["kbar"]))
-        if "quarantined" in metrics:
-            hist.quarantined.append(float(metrics["quarantined"]))
+        hist.record(metrics)
         self._record_dropped(hist, t, 1)
         self._record_bytes(hist, 1, self.fed.n_clients)
 
@@ -383,14 +393,7 @@ class FederatedSimulation:
             with jax.profiler.TraceAnnotation("fed.wait"):
                 jax.block_until_ready(self.state)
             with jax.profiler.TraceAnnotation("fed.history"):
-                hist.loss.extend(
-                    np.asarray(metrics["loss"], np.float64).tolist())
-                hist.kbar.extend(
-                    np.asarray(metrics["kbar"], np.float64).tolist())
-                if "quarantined" in metrics:
-                    hist.quarantined.extend(
-                        np.asarray(metrics["quarantined"],
-                                   np.float64).tolist())
+                hist.record(metrics)
                 self._record_dropped(hist, t0, r)
                 self._record_bytes(hist, r, self.fed.n_clients)
 
@@ -431,11 +434,7 @@ class FederatedSimulation:
                                  jnp.asarray(k_c, jnp.int32),
                                  jnp.asarray(cw), jnp.float32(lam))
         jax.block_until_ready(self.state)
-        hist.loss.append(float(metrics["loss"]))
-        hist.kbar.append(float(metrics["kbar"]))
-        hist.mass.append(float(metrics["mass"]))
-        if "quarantined" in metrics:
-            hist.quarantined.append(float(metrics["quarantined"]))
+        hist.record(metrics)
         self._record_dropped(hist, t, 1)
         self._record_bytes(hist, 1, self.population.cohort_size)
 
@@ -473,12 +472,7 @@ class FederatedSimulation:
                     jnp.asarray(ks), jnp.asarray(cws), lams)
         self.state, metrics = chunk_fn(self.state, *args)
         jax.block_until_ready(self.state)
-        hist.loss.extend(np.asarray(metrics["loss"], np.float64).tolist())
-        hist.kbar.extend(np.asarray(metrics["kbar"], np.float64).tolist())
-        hist.mass.extend(np.asarray(metrics["mass"], np.float64).tolist())
-        if "quarantined" in metrics:
-            hist.quarantined.extend(
-                np.asarray(metrics["quarantined"], np.float64).tolist())
+        hist.record(metrics)
         self._record_dropped(hist, t0, r)
         self._record_bytes(hist, r, self.population.cohort_size)
 

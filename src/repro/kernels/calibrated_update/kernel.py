@@ -6,6 +6,10 @@ ops (add, mul, sub) ⇒ up to 3 reads + intermediate writes of a full
 parameter-sized tensor per local step.  The fused kernel streams x, g, c
 through VMEM once: 3 reads + 1 write, the bandwidth floor.
 
+The output takes x's buffer (``input_output_aliases``): a caller whose x
+is dead after the step — the flat client loop's carry — updates it in
+place, and XLA copies x first only where the caller still reads it.
+
 TPU adaptation: the parameter pytree is flattened and lane-padded to
 (rows, 128·k); each grid step processes one VMEM tile of at most
 BLOCK_ROWS × 128 elements (``tile_2d``) — the last-dim multiple-of-128
@@ -79,6 +83,7 @@ def calibrated_update_2d(x: jax.Array, g: jax.Array, c: jax.Array,
                   spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        input_output_aliases={1: 0},
         interpret=interpret,
     )(scal, x, g, c)
 
@@ -103,5 +108,6 @@ def calibrated_update_prox_2d(x, g, c, x0, eta, lam, mu, *,
                   spec, spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        input_output_aliases={1: 0},
         interpret=interpret,
     )(scal, x, g, c, x0)

@@ -67,13 +67,15 @@ def mesh_rules(mesh, *, kind: str) -> dict[str, tuple[str, ...]]:
     """Logical→physical rules per step kind (see dist/sharding.py).
 
     train   : client axis is consumed by vmap(spmd_axis_name=data axes);
-              inside the per-client function dp is unmapped.
+              inside the per-client function dp is unmapped.  ``cl``
+              names the flat round's client rows (over the data axes).
     prefill/decode : batch over data axes, tensor over model.
     long    : batch=1 ⇒ dp unmapped, KV-cache sequence over data ("sp").
     """
     batch = ("batch",) if "batch" in mesh.axis_names else ()
     if kind == "train":
-        return {"dp": batch, "mp": model_axes(mesh), "sp": ()}
+        return {"dp": batch, "mp": model_axes(mesh), "sp": (),
+                "cl": data_axes(mesh)}
     if kind in ("prefill", "decode"):
         return {"dp": data_axes(mesh) + batch, "mp": model_axes(mesh),
                 "sp": ()}

@@ -360,3 +360,239 @@ def test_unknown_layout_raises():
     with pytest.raises(ValueError, match="param_layout"):
         BufferedAsyncSimulation(lr_loss, params, fed,
                                 FederatedBatcher(data, parts, 10))
+
+
+# ---------------------------------------------------------------------------
+# the client update: clients in turn, each its own K_i steps
+# ---------------------------------------------------------------------------
+
+def _masked_scan(spec, loss_fn, algo, *, lr, k_max, track_nu, anchor, c_all,
+                 batches, k_steps, lam, per_client_anchor):
+    """The client update as it was before clients ran in turn: a k_max-step
+    scan vmapped over the clients, each row past its K_i masked by a zero
+    step size (jnp arithmetic)."""
+    needs_first = algo.selector in ("fedagrac", "first", "reverse")
+    explicit = track_nu == "explicit" and algo.uses_nu
+    fuse_prox = bool(algo.prox_mu) and not (needs_first or explicit)
+    m = k_steps.shape[0]
+    anchors = (anchor if per_client_anchor
+               else jnp.broadcast_to(anchor[None], (m, spec.p)))
+    lam = lam if algo.uses_nu else 0.0
+    grad = jax.vmap(flat.flat_value_and_grad(spec, loss_fn))
+
+    def step(carry, xs):
+        k, batch = xs
+        x, g0, acc = carry
+        loss, g = grad(x, batch)
+        if algo.prox_mu and not fuse_prox:
+            g = g + algo.prox_mu * (x - anchors)
+        eta = jnp.where(k < k_steps, jnp.float32(lr), 0.0)[:, None]
+        t = g + lam * c_all if algo.uses_nu else g
+        if fuse_prox:
+            t = t + algo.prox_mu * (x - anchors)
+        x = x - eta * t
+        g0 = jnp.where(k == 0, g, g0)
+        w = jnp.where(k < k_steps, 1.0 / k_steps.astype(jnp.float32), 0.0)
+        return (x, g0, acc + w[:, None] * g), loss
+
+    zeros = jnp.zeros((m, spec.p), spec.dtype)
+    (x, g0, acc), losses = jax.lax.scan(
+        step, (anchors, zeros, zeros),
+        (jnp.arange(k_max), jax.tree.map(lambda b: jnp.swapaxes(b, 0, 1),
+                                         batches)))
+    return (x, g0 if needs_first else None, acc if explicit else None,
+            losses[0])
+
+
+def _prox_fedagrac():
+    import dataclasses
+    return dataclasses.replace(_algo("fedagrac"), prox_mu=0.1)
+
+
+SERIAL_CASES = {
+    # name: (algorithm, update kwargs, k_steps, k_max, per_client_anchor)
+    "fedagrac_delta": (lambda: _algo("fedagrac"), {}, KS, K_MAX, False),
+    "fedagrac_explicit_nu": (lambda: _algo("fedagrac"),
+                             {"track_nu": "explicit"}, KS, K_MAX, False),
+    "prox_fused": (lambda: _algo("fedprox"), {}, KS, K_MAX, False),
+    "prox_unfused": (_prox_fedagrac, {}, KS, K_MAX, False),
+    "per_client_anchor": (lambda: _algo("fedagrac"), {}, KS, K_MAX, True),
+    # partial work: each client aborts after k′ < K_i of its steps
+    "partial_work": (lambda: _algo("fedagrac"), {},
+                     jnp.array([1, 2, 3, 5], jnp.int32), K_MAX, False),
+    "k_max_1": (lambda: _algo("fedagrac"), {}, jnp.ones((M,), jnp.int32), 1,
+                False),
+    "pallas_kernel": (lambda: _algo("fedagrac"),
+                      {"use_pallas": True, "interpret": True}, KS, K_MAX,
+                      False),
+}
+
+
+def _client_inputs(per_client_anchor, key=3):
+    rng = np.random.default_rng(key)
+    shape = (M, SPEC.p) if per_client_anchor else (SPEC.p,)
+    pad = lambda a: a.at[..., SPEC.n:].set(0.0)
+    anchor = pad(jnp.asarray(rng.normal(size=shape).astype(np.float32)))
+    c_all = pad(jnp.asarray(
+        0.1 * rng.normal(size=(M, SPEC.p)).astype(np.float32)))
+    return anchor, c_all
+
+
+@pytest.mark.parametrize("case", SERIAL_CASES)
+def test_client_serial_update_matches_masked_scan(case):
+    """Clients in turn, each a loop of its own K_i steps, against the
+    vmapped k_max-step scan with rows past K_i masked: every output to
+    float32 tolerance, and Σ K_i client-steps in place of M · k_max."""
+    make_algo, kw, ks, k_max, per_client = SERIAL_CASES[case]
+    algo = make_algo()
+    track_nu = kw.get("track_nu", "delta")
+    anchor, c_all = _client_inputs(per_client)
+    b = jax.tree.map(lambda a: a[:, :k_max], _batches())
+    cu = jax.jit(flat.make_flat_client_update(
+        SPEC, quad_loss, algo, lr=0.01, k_max=k_max,
+        per_client_anchor=per_client, **kw))
+    x, g0, acc, loss0, steps = cu(anchor, c_all, b, ks, jnp.float32(0.5))
+    want = jax.jit(lambda a, c, bb, k: _masked_scan(
+        SPEC, quad_loss, algo, lr=0.01, k_max=k_max, track_nu=track_nu,
+        anchor=a, c_all=c, batches=bb, k_steps=k, lam=jnp.float32(0.5),
+        per_client_anchor=per_client))(anchor, c_all, b, ks)
+    for got, ref in zip((x, g0, acc, loss0), want):
+        if ref is not None:
+            np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                       rtol=RTOL, atol=ATOL)
+    assert int(steps) == int(np.minimum(np.asarray(ks), k_max).sum())
+
+
+def test_client_past_its_k_keeps_its_last_active_row():
+    """A client whose K_i is below k_max ends on the row of its K_i-th
+    step, bit for bit: the same client run with k_max = K_i."""
+    algo = _algo("fedagrac")
+    anchor, c_all = _client_inputs(False)
+    b = _batches()
+    run = lambda k_max, ks: flat.make_flat_client_update(
+        SPEC, quad_loss, algo, lr=0.01, k_max=k_max)(
+            anchor, c_all, jax.tree.map(lambda a: a[:, :k_max], b),
+            jnp.asarray(ks, jnp.int32), jnp.float32(0.5))
+    x_short = run(3, [3, 3, 3, 3])[0]
+    x_long = run(K_MAX, [3, 5, 8, 3])[0]
+    for i in (0, 3):
+        np.testing.assert_array_equal(np.asarray(x_long[i]),
+                                      np.asarray(x_short[i]))
+
+
+def test_round_evaluates_the_loss_sum_k_times():
+    """A jitted round with K = [1, 3] and k_max 3 runs the loss 4 times,
+    not M · k_max = 6: counted by the round's ``client_steps`` metric and
+    by a callback inside the loss."""
+    calls = []
+
+    def counted(params, batch):
+        jax.debug.callback(lambda: calls.append(1))
+        return quad_loss(params, batch)
+
+    algo = _algo("fedagrac")
+    m, k_max = 2, 3
+    state = flat.flatten_state(SPEC, rounds.init_state(dict(PARAMS), m,
+                                                       algo))
+    fn = jax.jit(flat.make_flat_round(SPEC, counted, algo, lr=0.01,
+                                      k_max=k_max))
+    b = jax.tree.map(lambda a: a[:m, :k_max], _batches())
+    _, metrics = fn(state, b, jnp.array([1, 3], jnp.int32),
+                    jnp.array([0.5, 0.5], jnp.float32))
+    jax.effects_barrier()
+    assert int(metrics["client_steps"]) == 4
+    assert len(calls) == 4
+
+
+@pytest.mark.parametrize("cell,schedule,per_chunk", [
+    ("granite-fedagrac-kasync", [[3, 4], [7, 5], [1, 4], [3, 4]], 31),
+    ("granite-fedagrac-ksync", [[4, 4]] * 4, 32),
+])
+def test_history_counts_the_client_steps_of_each_round(cell, schedule,
+                                                       per_chunk):
+    """The flat simulation records each round's client-steps in History:
+    under the chip cells' K schedules (M = 2, 4-round chunks) a chunk
+    computes 31 and 32 of them."""
+    data, parts, params, _ = _lr_task()
+    fed = FedConfig(algorithm="fedagrac", n_clients=2, lr=0.05,
+                    calibration_rate=0.5, param_layout="flat")
+    sim = FederatedSimulation(lr_loss, params, fed,
+                              DeviceBatcher(data, parts[:2], 10),
+                              k_schedule=np.array(schedule, np.int32))
+    hist = sim.run(8, eval_every=4)
+    assert hist.client_steps == [float(sum(r)) for r in schedule] * 2
+    assert sum(hist.client_steps[:4]) == per_chunk
+
+
+def _run_on_host_devices(code: str, devices: int = 4) -> str:
+    """Run ``code`` in a fresh interpreter with ``devices`` CPU devices
+    (XLA fixes the count at its first initialisation)."""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["PYTHONPATH"] = os.path.join(repo, "src")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    return out.stdout
+
+
+@pytest.mark.parametrize("mesh_shape,steps", [
+    (None, 13),          # no mesh: Σ K_i = 1 + 3 + 5 + 4
+    ((4, 1), 4 * 5),     # client rows over 4 devices: M · k_max
+    ((1, 4), 13),        # rows on every device, P split: clients in turn
+])
+def test_client_steps_follow_where_the_client_rows_live(mesh_shape, steps):
+    """The flat round runs Σ K_i client-steps where each client's row sits
+    whole on a device and the masked M · k_max scan where the mesh spreads
+    the rows over its data axes (launch/train.py's round); both reach the
+    single-device round's state."""
+    code = r"""
+import jax, jax.numpy as jnp, numpy as np
+from repro import dist
+from repro.configs.base import FedConfig
+from repro.core import flat, rounds
+from repro.core.fedopt import get_algorithm
+from repro.launch import train as train_lib
+from repro.launch.mesh import make_local_mesh, mesh_rules
+from repro.models.simple import quad_loss
+
+MESH = %r
+m, d, k_max = 4, 6, 5
+algo = get_algorithm("fedagrac", FedConfig(algorithm="fedagrac", n_clients=m,
+                                           lr=0.01, calibration_rate=0.5))
+params = {"x": jnp.zeros((d,), jnp.float32)}
+spec = flat.make_flat_spec(params)
+rng = np.random.default_rng(0)
+b = {"A": jnp.asarray(rng.normal(size=(m, k_max, d, d)), jnp.float32),
+     "b": jnp.asarray(rng.normal(size=(m, k_max, d)), jnp.float32),
+     "c0": jnp.zeros((m, k_max))}
+ks = jnp.array([1, 3, 5, 4], jnp.int32)
+w = jnp.full((m,), 0.25, jnp.float32)
+state = flat.flatten_state(spec, rounds.init_state(params, m, algo))
+one = jax.jit(flat.make_flat_round(spec, quad_loss, algo, lr=0.01,
+                                   k_max=k_max))
+ref_state, ref_metrics = one(state, b, ks, w)
+ref_state, ref_metrics = one(ref_state, b, ks, w)
+if MESH is None:
+    got_state, metrics = ref_state, ref_metrics
+else:
+    mesh = make_local_mesh(*MESH)
+    fn = dist.traced_under(mesh, mesh_rules(mesh, kind="train"),
+                           flat.make_flat_round(
+        spec, quad_loss, algo, lr=0.01, k_max=k_max,
+        param_constraint=train_lib.make_flat_param_constraint(mesh, spec.p)))
+    with jax.set_mesh(mesh):
+        fn = jax.jit(fn)
+        got_state, metrics = fn(state, b, ks, w)
+        got_state, metrics = fn(got_state, b, ks, w)
+    for k in ref_state:
+        np.testing.assert_allclose(np.asarray(got_state[k], np.float32),
+                                   np.asarray(ref_state[k], np.float32),
+                                   rtol=1e-6, atol=1e-7, err_msg=k)
+print(int(metrics["client_steps"]))
+""" % (mesh_shape,)
+    assert int(_run_on_host_devices(code).split()[-1]) == steps
